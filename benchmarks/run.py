@@ -16,6 +16,8 @@ import os
 import sys
 import traceback
 
+from repro.runtime.compile_cache import enable_compile_cache
+
 BENCHES = ("fig1", "fig4a", "fig4c", "table1", "kpi", "roofline", "serve")
 # Benchmarks with a --smoke-aware run(smoke=...) and a JSON artifact.
 JSON_OUT = {"kpi": "BENCH_decode.json", "serve": "BENCH_serve.json",
@@ -34,6 +36,7 @@ def main() -> None:
                     help="reduced iteration counts (CI); restricts the "
                          "default set to the JSON-producing benchmarks")
     args = ap.parse_args()
+    enable_compile_cache()
     want = [w for w in args.only.split(",") if w]
     if not want:
         want = list(JSON_OUT) if args.smoke else list(BENCHES)

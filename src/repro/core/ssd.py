@@ -155,8 +155,8 @@ def ssd(x: Array, dt: Array, A: Array, B: Array, C: Array, *,
     # never silently runs unfused.
     use_kernel = cs_mode in ("pallas", "pallas_interpret")
     if use_kernel and chunk_size % 64:
-        log.info("ssd_chunk kernel (%s) skipped: chunk %d not a multiple "
-                 "of 64 — running the XLA chain", cs_mode, chunk_size)
+        log.warning("ssd_chunk kernel (%s) skipped: chunk %d not a multiple "
+                    "of 64 — running the XLA chain", cs_mode, chunk_size)
         use_kernel = False
     if use_kernel:
         from repro.kernels import ops as kops

@@ -28,17 +28,11 @@ BLOCK = 256
 
 
 def shard_map(f, *, mesh, axis_names, in_specs, out_specs):
-    """``jax.shard_map`` compat: newer jax takes ``axis_names``/``check_vma``;
-    older jax (<= 0.4.x) exposes ``jax.experimental.shard_map`` with the
-    complementary ``auto`` set and ``check_rep`` instead."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, axis_names=set(axis_names),
-                             in_specs=in_specs, out_specs=out_specs,
-                             check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    auto = frozenset(mesh.axis_names) - set(axis_names)
-    return _shard_map(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False, auto=auto)
+    """``jax.shard_map`` manual over ``axis_names`` only; the other mesh
+    axes stay under automatic partitioning."""
+    return jax.shard_map(f, mesh=mesh, axis_names=set(axis_names),
+                         in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)
 
 
 def _quantize(x: jax.Array) -> Tuple[jax.Array, jax.Array]:

@@ -12,30 +12,41 @@ NEG_INF = -1e30
 RG_LRU_C = 8.0
 
 
-def compiler_params(dimension_semantics):
-    """Best-effort TPU compiler params across pallas API versions."""
-    for name in ("CompilerParams", "TPUCompilerParams"):
-        cls = getattr(pltpu, name, None)
-        if cls is not None:
-            try:
-                return cls(dimension_semantics=tuple(dimension_semantics))
-            except TypeError:
-                pass
-    return None
-
-
 def pallas_call(kernel, *, grid, in_specs, out_specs, out_shape,
                 scratch_shapes=(), interpret=False, dimension_semantics=None,
-                name=None):
+                vmem_limit_bytes=None, name=None):
+    """``pl.pallas_call`` with TPU compiler params.  ``vmem_limit_bytes``
+    raises Mosaic's scoped-VMEM limit (16 MiB by default; a v5e core has
+    128 MiB) for kernels whose working set is a whole chunk."""
     kwargs = {}
-    if dimension_semantics is not None and not interpret:
-        cp = compiler_params(dimension_semantics)
-        if cp is not None:
-            kwargs["compiler_params"] = cp
+    if not interpret:
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=(None if dimension_semantics is None
+                                 else tuple(dimension_semantics)),
+            vmem_limit_bytes=vmem_limit_bytes)
     return pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
         out_shape=out_shape, scratch_shapes=list(scratch_shapes),
         interpret=interpret, name=name, **kwargs)
+
+
+def dot(a, b, contract=((1,), (0,))):
+    """In-kernel 2-D contraction with an fp32 accumulator.  fp32 operands
+    run at HIGHEST precision (Mosaic otherwise rounds them to bf16 passes);
+    bf16 operands keep the default, whose products are already exact and
+    which is the only precision Mosaic accepts for them."""
+    prec = (jax.lax.Precision.HIGHEST if a.dtype == jnp.float32 else None)
+    return jax.lax.dot_general(a, b, (contract, ((), ())), precision=prec,
+                               preferred_element_type=jnp.float32)
+
+
+def head_expand(h: int, p: int):
+    """(h, h*p) 0/1 matrix: ``v @ E`` repeats each head's scalar p times,
+    so per-head values reach their channels without a lane->sublane
+    relayout (built from iotas: a compile-time constant in VMEM)."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (h, h * p), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (h, h * p), 1)
+    return ((cols >= rows * p) & (cols < rows * p + p)).astype(jnp.float32)
 
 
 def round_up(x: int, m: int) -> int:

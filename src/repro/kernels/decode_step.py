@@ -20,10 +20,22 @@ Activations honor ActiBA: callers pass the (compile-time) activation
 callables from ``core.pwl.activation`` so the PWL tables are baked into
 the kernel body, exactly like the NPU's C-LUT programming.
 
-Grids are one program per batch row (decode batches are slot counts —
-small); every ref keeps >= 2 dims for TPU layout friendliness.  On CPU
-use ``interpret=True``; numerics are fp32 throughout, tied to the
-``kernels/ref.py`` oracles at <= 1e-5.
+TPU layout rules shape the code (Mosaic tiles the last two dims of every
+block by (8, 128)):
+
+* one program per batch row; per-row vectors travel as ``(b, 1, d)``
+  so a row block ``(1, 1, d)`` spans its array's last two dims;
+* SSM states travel as ``(b, h*p, n)`` — the same bytes as
+  ``(b, h, p, n)``, but the update becomes 2-D: each channel is a
+  sublane row and ``n`` the lanes;
+* a channel-major column never has to be built from a lane row: outer
+  products are K=1 ``dot_general``s contracting the leading axis, the
+  state->output contraction is an ``(1, n) x (rows, n)^T`` matmul, and
+  per-head scalars expand to channels through a 0/1 ``(h, h*p)`` matmul.
+
+On CPU use ``interpret=True``; numerics are fp32 throughout (fp32
+matmuls at ``HIGHEST`` precision; the RG-LRU gate weights stay in their
+storage dtype), tied to the ``kernels/ref.py`` oracles at <= 1e-5.
 """
 from __future__ import annotations
 
@@ -51,26 +63,44 @@ def _rep(shape):
     return pl.BlockSpec(tuple(shape), lambda i, _nd=ndim: (0,) * (_nd))
 
 
+def _rows(x):
+    """(b, d) -> (b, 1, d): row blocks that span the array's last dims."""
+    return x.reshape(x.shape[0], 1, x.shape[1])
+
+
 def _f32(ref):
     return ref[...].astype(jnp.float32)
+
+
+def _outer(u, v):
+    """(1, m) x (1, n) -> (m, n): a K=1 contraction over the leading axis
+    (the MXU builds the column layout the lane row cannot)."""
+    return common.dot(u, v, ((0,), (0,)))
+
+
+def _rowdot(c, m):
+    """(1, n) x (rows, n)^T -> (1, rows)."""
+    return common.dot(c, m, ((1,), (1,)))
 
 
 # ============================================================================
 # Bare recurrent updates (core-level dispatch targets)
 # ============================================================================
 
-def _ssd_update(st, x, dt, A, B, C):
-    """st (h,p,n), x (h,p), dt/A (1,h), B/C (g,n) -> (new_st, y (h,p))."""
-    h, p, n = st.shape
-    g = B.shape[0]
-    hpg = h // g
-    decay = jnp.exp(dt[0] * A[0])                            # (h,)
-    Bh = jnp.broadcast_to(B[:, None, :], (g, hpg, n)).reshape(h, n)
-    Ch = jnp.broadcast_to(C[:, None, :], (g, hpg, n)).reshape(h, n)
-    new = st * decay[:, None, None] + \
-        (dt[0][:, None] * x)[..., None] * Bh[:, None, :]
-    y = jnp.sum(new * Ch[:, None, :], axis=-1)               # (h, p)
-    return new, y
+def _ssd_update(st, xdt, decay, B, C, g):
+    """st (h*p, n); xdt/decay (1, h*p) per channel; B/C (1, g*n).
+    Returns (new_st (h*p, n), y (1, h*p))."""
+    m = st.shape[0] // g
+    n = st.shape[1]
+    ones = jnp.ones((1, n), jnp.float32)
+    news, ys = [], []
+    for gi in range(g):
+        r = slice(gi * m, (gi + 1) * m)
+        Bg, Cg = B[:, gi * n:(gi + 1) * n], C[:, gi * n:(gi + 1) * n]
+        new = st[r] * _outer(decay[:, r], ones) + _outer(xdt[:, r], Bg)
+        news.append(new)
+        ys.append(_rowdot(Cg, new))
+    return jnp.concatenate(news, axis=0), jnp.concatenate(ys, axis=1)
 
 
 def ssd_step(state: Array, x_t: Array, dt_t: Array, A: Array,
@@ -82,31 +112,37 @@ def ssd_step(state: Array, x_t: Array, dt_t: Array, A: Array,
     A2 = A.reshape(1, h).astype(jnp.float32)
 
     def kernel(st_ref, x_ref, dt_ref, a_ref, b_ref, c_ref, ns_ref, y_ref):
-        new, y = _ssd_update(_f32(st_ref)[0], _f32(x_ref)[0], _f32(dt_ref),
-                             _f32(a_ref), _f32(b_ref)[0], _f32(c_ref)[0])
+        E = common.head_expand(h, p)
+        dt = _f32(dt_ref)[0]                                 # (1, h)
+        new, y = _ssd_update(_f32(st_ref)[0],
+                             _f32(x_ref)[0] * common.dot(dt, E),
+                             common.dot(jnp.exp(dt * _f32(a_ref)), E),
+                             _f32(b_ref)[0], _f32(c_ref)[0], g)
         ns_ref[0] = new.astype(ns_ref.dtype)
         y_ref[0] = y.astype(y_ref.dtype)
 
     new_state, y = common.pallas_call(
         kernel, grid=(b,),
-        in_specs=[_row((h, p, n)), _row((h, p)), _row((h,)), _rep((1, h)),
-                  _row((g, n)), _row((g, n))],
-        out_specs=(_row((h, p, n)), _row((h, p))),
-        out_shape=(jax.ShapeDtypeStruct((b, h, p, n), jnp.float32),
-                   jax.ShapeDtypeStruct((b, h, p), x_t.dtype)),
+        in_specs=[_row((h * p, n)), _row((1, h * p)), _row((1, h)),
+                  _rep((1, h)), _row((1, g * n)), _row((1, g * n))],
+        out_specs=(_row((h * p, n)), _row((1, h * p))),
+        out_shape=(jax.ShapeDtypeStruct((b, h * p, n), jnp.float32),
+                   jax.ShapeDtypeStruct((b, 1, h * p), x_t.dtype)),
         dimension_semantics=("parallel",),
         interpret=interpret, name="ssd_decode_step",
-    )(state, x_t, dt_t, A2, B_t, C_t)
-    return new_state, y
+    )(state.reshape(b, h * p, n), x_t.reshape(b, 1, h * p), _rows(dt_t),
+      A2, B_t.reshape(b, 1, g * n), C_t.reshape(b, 1, g * n))
+    return new_state.reshape(b, h, p, n), y.reshape(b, h, p)
 
 
 def _sscan_update(st, u, dt, A, B, C, D):
-    """st (d,n), u/dt (1,d), A (d,n), B/C (1,n), D (1,d) or None."""
-    decay = jnp.exp(dt[0][:, None] * A)                      # (d, n)
-    new = st * decay + (dt[0] * u[0])[:, None] * B[0][None, :]
-    y = jnp.sum(new * C[0][None, :], axis=-1)                # (d,)
+    """st/A (d,n); u/dt (1,d); B/C (1,n); D (1,d) or None ->
+    (new_st (d,n), y (1,d))."""
+    ones = jnp.ones((1, st.shape[1]), jnp.float32)
+    new = st * jnp.exp(_outer(dt, ones) * A) + _outer(dt * u, B)
+    y = _rowdot(C, new)
     if D is not None:
-        y = y + D[0] * u[0]
+        y = y + D * u
     return new, y
 
 
@@ -121,23 +157,25 @@ def sscan_step(state: Array, u_t: Array, delta_t: Array, A: Array,
 
     def kernel(st_ref, u_ref, dt_ref, a_ref, b_ref, c_ref, d_ref,
                ns_ref, y_ref):
-        new, y = _sscan_update(_f32(st_ref)[0], _f32(u_ref), _f32(dt_ref),
-                               _f32(a_ref), _f32(b_ref), _f32(c_ref),
+        new, y = _sscan_update(_f32(st_ref)[0], _f32(u_ref)[0],
+                               _f32(dt_ref)[0], _f32(a_ref),
+                               _f32(b_ref)[0], _f32(c_ref)[0],
                                _f32(d_ref) if has_d else None)
         ns_ref[0] = new.astype(ns_ref.dtype)
         y_ref[0] = y.astype(y_ref.dtype)
 
     new_state, y = common.pallas_call(
         kernel, grid=(b,),
-        in_specs=[_row((d, n)), _row((d,)), _row((d,)), _rep((d, n)),
-                  _row((n,)), _row((n,)), _rep((1, d))],
-        out_specs=(_row((d, n)), _row((d,))),
+        in_specs=[_row((d, n)), _row((1, d)), _row((1, d)), _rep((d, n)),
+                  _row((1, n)), _row((1, n)), _rep((1, d))],
+        out_specs=(_row((d, n)), _row((1, d))),
         out_shape=(jax.ShapeDtypeStruct((b, d, n), jnp.float32),
-                   jax.ShapeDtypeStruct((b, d), u_t.dtype)),
+                   jax.ShapeDtypeStruct((b, 1, d), u_t.dtype)),
         dimension_semantics=("parallel",),
         interpret=interpret, name="sscan_decode_step",
-    )(state, u_t, delta_t, A.astype(jnp.float32), B_t, C_t, D2)
-    return new_state, y
+    )(state, _rows(u_t), _rows(delta_t), A.astype(jnp.float32),
+      _rows(B_t), _rows(C_t), D2)
+    return new_state, y.reshape(b, d)
 
 
 # ============================================================================
@@ -184,38 +222,41 @@ def mamba2_step(z: Array, xbc: Array, dt: Array, conv_state: Array,
 
     def kernel(z_ref, xbc_ref, dt_ref, cs_ref, st_ref, cw_ref, cb_ref,
                dtb_ref, a_ref, d_ref, nsc_ref, y_ref, nc_ref, nst_ref):
-        conv_out, new_conv = _conv_shift(_f32(cs_ref)[0], _f32(xbc_ref),
+        conv_out, new_conv = _conv_shift(_f32(cs_ref)[0], _f32(xbc_ref)[0],
                                          _f32(cw_ref), _f32(cb_ref))
         act = silu(conv_out)                                 # (1, dxbc)
-        xs = act[0, :di].reshape(h, p)
-        B = act[0, di:di + g * n].reshape(g, n)
-        C = act[0, di + g * n:].reshape(g, n)
-        dt_f = softplus(_f32(dt_ref) + _f32(dtb_ref))        # (1, h)
-        new, y = _ssd_update(_f32(st_ref)[0], xs, dt_f, _f32(a_ref), B, C)
-        y = y + _f32(d_ref)[0][:, None] * xs                 # D skip
-        yf = y.reshape(1, di)
-        ms = jnp.mean(yf * yf, axis=-1, keepdims=True)
-        yn = yf * jax.lax.rsqrt(ms + eps) * _f32(nsc_ref)    # gated RMSNorm
-        out = yn * silu(_f32(z_ref))
-        y_ref[...] = out.astype(y_ref.dtype)
+        xs = act[:, :di]
+        B = act[:, di:di + g * n]
+        C = act[:, di + g * n:]
+        E = common.head_expand(h, p)
+        dt_f = softplus(_f32(dt_ref)[0] + _f32(dtb_ref))     # (1, h)
+        new, y = _ssd_update(_f32(st_ref)[0], xs * common.dot(dt_f, E),
+                             common.dot(jnp.exp(dt_f * _f32(a_ref)), E),
+                             B, C, g)
+        y = y + common.dot(_f32(d_ref), E) * xs              # D skip
+        ms = jnp.mean(y * y, axis=-1, keepdims=True)
+        yn = y * jax.lax.rsqrt(ms + eps) * _f32(nsc_ref)     # gated RMSNorm
+        out = yn * silu(_f32(z_ref)[0])
+        y_ref[0] = out.astype(y_ref.dtype)
         nc_ref[0] = new_conv.astype(nc_ref.dtype)
         nst_ref[0] = new.astype(nst_ref.dtype)
 
     y, new_conv, new_ssm = common.pallas_call(
         kernel, grid=(b,),
-        in_specs=[_row((di,)), _row((dxbc,)), _row((h,)),
-                  _row((w - 1, dxbc)), _row((h, p, n)),
+        in_specs=[_row((1, di)), _row((1, dxbc)), _row((1, h)),
+                  _row((w - 1, dxbc)), _row((h * p, n)),
                   _rep((w, dxbc)), _rep((1, dxbc)), _rep((1, h)),
                   _rep((1, h)), _rep((1, h)), _rep((1, di))],
-        out_specs=(_row((di,)), _row((w - 1, dxbc)), _row((h, p, n))),
-        out_shape=(jax.ShapeDtypeStruct((b, di), z.dtype),
+        out_specs=(_row((1, di)), _row((w - 1, dxbc)), _row((h * p, n))),
+        out_shape=(jax.ShapeDtypeStruct((b, 1, di), z.dtype),
                    jax.ShapeDtypeStruct((b, w - 1, dxbc), conv_state.dtype),
-                   jax.ShapeDtypeStruct((b, h, p, n), jnp.float32)),
+                   jax.ShapeDtypeStruct((b, h * p, n), jnp.float32)),
         dimension_semantics=("parallel",),
         interpret=interpret, name="mamba2_decode_step",
-    )(z, xbc, dt, conv_state, ssm_state, conv_w.astype(jnp.float32),
+    )(_rows(z), _rows(xbc), _rows(dt), conv_state,
+      ssm_state.reshape(b, h * p, n), conv_w.astype(jnp.float32),
       conv_b2, dtb2, A2, D2, ns2)
-    return y, new_conv, new_ssm
+    return y.reshape(b, di), new_conv, new_ssm.reshape(b, h, p, n)
 
 
 def mamba1_step(xs_raw: Array, z: Array, conv_state: Array, ssm_state: Array,
@@ -243,40 +284,41 @@ def mamba1_step(xs_raw: Array, z: Array, conv_state: Array, ssm_state: Array,
 
     def kernel(x_ref, z_ref, cs_ref, st_ref, cw_ref, cb_ref, xp_ref,
                dtw_ref, dtb_ref, a_ref, d_ref, y_ref, nc_ref, nst_ref):
-        conv_out, new_conv = _conv_shift(_f32(cs_ref)[0], _f32(x_ref),
+        conv_out, new_conv = _conv_shift(_f32(cs_ref)[0], _f32(x_ref)[0],
                                          _f32(cw_ref), _f32(cb_ref))
         xs = silu(conv_out)                                  # (1, di)
-        dbc = jnp.dot(xs, _f32(xp_ref),
-                      preferred_element_type=jnp.float32)    # (1, r+2n)
+        dbc = common.dot(xs, _f32(xp_ref))                   # (1, r+2n)
         dt_low, B, C = dbc[:, :r], dbc[:, r:r + n], dbc[:, r + n:]
-        dt_f = softplus(jnp.dot(dt_low, _f32(dtw_ref),
-                                preferred_element_type=jnp.float32) +
-                        _f32(dtb_ref))                       # (1, di)
+        dt_f = softplus(common.dot(dt_low, _f32(dtw_ref)) + _f32(dtb_ref))
         new, y = _sscan_update(_f32(st_ref)[0], xs, dt_f, _f32(a_ref),
                                B, C, _f32(d_ref))
-        out = y[None] * silu(_f32(z_ref))
-        y_ref[...] = out.astype(y_ref.dtype)
+        out = y * silu(_f32(z_ref)[0])
+        y_ref[0] = out.astype(y_ref.dtype)
         nc_ref[0] = new_conv.astype(nc_ref.dtype)
         nst_ref[0] = new.astype(nst_ref.dtype)
 
     y, new_conv, new_ssm = common.pallas_call(
         kernel, grid=(b,),
-        in_specs=[_row((di,)), _row((di,)), _row((w - 1, di)),
+        in_specs=[_row((1, di)), _row((1, di)), _row((w - 1, di)),
                   _row((di, n)), _rep((w, di)), _rep((1, di)),
                   _rep((di, r + 2 * n)), _rep((r, di)), _rep((1, di)),
                   _rep((di, n)), _rep((1, di))],
-        out_specs=(_row((di,)), _row((w - 1, di)), _row((di, n))),
-        out_shape=(jax.ShapeDtypeStruct((b, di), z.dtype),
+        out_specs=(_row((1, di)), _row((w - 1, di)), _row((di, n))),
+        out_shape=(jax.ShapeDtypeStruct((b, 1, di), z.dtype),
                    jax.ShapeDtypeStruct((b, w - 1, di), conv_state.dtype),
                    jax.ShapeDtypeStruct((b, di, n), jnp.float32)),
         dimension_semantics=("parallel",),
         interpret=interpret, name="mamba1_decode_step",
-    )(xs_raw, z, conv_state, ssm_state, conv_w.astype(jnp.float32),
-      conv_b2, xproj_w, dtproj_w, dtb2, A.astype(jnp.float32), D2)
-    return y, new_conv, new_ssm
+    )(_rows(xs_raw), _rows(z), conv_state, ssm_state,
+      conv_w.astype(jnp.float32), conv_b2, xproj_w, dtproj_w, dtb2,
+      A.astype(jnp.float32), D2)
+    return y.reshape(b, di), new_conv, new_ssm
 
 
 _RG_C = common.RG_LRU_C  # Griffin's fixed gate exponent
+# Output channels per RG-LRU program: the (w, w) gate weights stream
+# through VMEM as (w, tile) column blocks instead of whole matrices.
+_RG_TILE = 256
 
 
 def rglru_step(u: Array, gate: Array, conv_state: Array, h_state: Array,
@@ -291,45 +333,59 @@ def rglru_step(u: Array, gate: Array, conv_state: Array, h_state: Array,
     u/gate (b,w) — the ``in_x``/``in_gate`` projections; conv_state
     (b,wc-1,w); h_state (b,w); rg_w/ig_w (w,w) with (w,) biases; lam (w,).
     Returns (y (b,w) — gated, pre-``out``; new_conv; new_h).
+
+    Grid ``(w // tile, b)``: program ``(j, i)`` owns output channels
+    ``j*tile:(j+1)*tile`` of row ``i``.  It convolves the whole row (the
+    gate matmuls contract over every channel) and its own channel tile;
+    batch is the inner axis, so each weight tile is fetched once.
     """
     b, wd = u.shape
     wc = conv_w.shape[0]
+    tn = _RG_TILE if wd % _RG_TILE == 0 else wd
 
+    conv_w32 = conv_w.astype(jnp.float32)
     conv_b2 = conv_b.reshape(1, wd).astype(jnp.float32)
     rgb2 = rg_b.reshape(1, wd).astype(jnp.float32)
     igb2 = ig_b.reshape(1, wd).astype(jnp.float32)
     lam2 = lam.reshape(1, wd).astype(jnp.float32)
+    u3, g3, h3 = _rows(u), _rows(gate), _rows(h_state)
 
-    def kernel(u_ref, g_ref, cs_ref, h_ref, cw_ref, cb_ref, rw_ref, rb_ref,
-               iw_ref, ib_ref, lam_ref, y_ref, nc_ref, nh_ref):
-        u_c, new_conv = _conv_shift(_f32(cs_ref)[0], _f32(u_ref),
-                                    _f32(cw_ref), _f32(cb_ref))
-        r = sigmoid(jnp.dot(u_c, _f32(rw_ref),
-                            preferred_element_type=jnp.float32) + _f32(rb_ref))
-        i = sigmoid(jnp.dot(u_c, _f32(iw_ref),
-                            preferred_element_type=jnp.float32) + _f32(ib_ref))
+    def kernel(u_ref, cs_ref, cw_ref, cb_ref, ut_ref, cst_ref, cwt_ref,
+               cbt_ref, g_ref, h_ref, rw_ref, rb_ref, iw_ref, ib_ref,
+               lam_ref, y_ref, nc_ref, nh_ref):
+        u_c, _ = _conv_shift(_f32(cs_ref)[0], _f32(u_ref)[0], _f32(cw_ref),
+                             _f32(cb_ref))                   # (1, w)
+        u_t, new_conv = _conv_shift(_f32(cst_ref)[0], _f32(ut_ref)[0],
+                                    _f32(cwt_ref), _f32(cbt_ref))
+        rw, iw = rw_ref[...], iw_ref[...]
+        r = sigmoid(common.dot(u_c.astype(rw.dtype), rw) + _f32(rb_ref))
+        i = sigmoid(common.dot(u_c.astype(iw.dtype), iw) + _f32(ib_ref))
         log_a = -_RG_C * softplus(_f32(lam_ref)) * r
         a = jnp.exp(log_a)
         gated_in = jnp.sqrt(jnp.maximum(1.0 - jnp.exp(2.0 * log_a), 1e-12)) \
-            * (i * u_c)
-        h_new = a * _f32(h_ref) + gated_in                   # (1, w)
-        out = h_new * gelu(_f32(g_ref))
-        y_ref[...] = out.astype(y_ref.dtype)
+            * (i * u_t)
+        h_new = a * _f32(h_ref)[0] + gated_in                # (1, tile)
+        out = h_new * gelu(_f32(g_ref)[0])
+        y_ref[0] = out.astype(y_ref.dtype)
         nc_ref[0] = new_conv.astype(nc_ref.dtype)
-        nh_ref[...] = h_new.astype(nh_ref.dtype)
+        nh_ref[0] = h_new.astype(nh_ref.dtype)
 
+    full3 = lambda d1: pl.BlockSpec((1, d1, wd), lambda j, i: (i, 0, 0))
+    tile3 = lambda d1: pl.BlockSpec((1, d1, tn), lambda j, i: (i, 0, j))
+    full2 = lambda d0: pl.BlockSpec((d0, wd), lambda j, i: (0, 0))
+    tile2 = lambda d0: pl.BlockSpec((d0, tn), lambda j, i: (0, j))
     y, new_conv, new_h = common.pallas_call(
-        kernel, grid=(b,),
-        in_specs=[_row((wd,)), _row((wd,)), _row((wc - 1, wd)), _row((wd,)),
-                  _rep((wc, wd)), _rep((1, wd)), _rep((wd, wd)),
-                  _rep((1, wd)), _rep((wd, wd)), _rep((1, wd)),
-                  _rep((1, wd))],
-        out_specs=(_row((wd,)), _row((wc - 1, wd)), _row((wd,))),
-        out_shape=(jax.ShapeDtypeStruct((b, wd), u.dtype),
+        kernel, grid=(wd // tn, b),
+        in_specs=[full3(1), full3(wc - 1), full2(wc), full2(1),
+                  tile3(1), tile3(wc - 1), tile2(wc), tile2(1),
+                  tile3(1), tile3(1), tile2(wd), tile2(1), tile2(wd),
+                  tile2(1), tile2(1)],
+        out_specs=(tile3(1), tile3(wc - 1), tile3(1)),
+        out_shape=(jax.ShapeDtypeStruct((b, 1, wd), u.dtype),
                    jax.ShapeDtypeStruct((b, wc - 1, wd), conv_state.dtype),
-                   jax.ShapeDtypeStruct((b, wd), jnp.float32)),
-        dimension_semantics=("parallel",),
+                   jax.ShapeDtypeStruct((b, 1, wd), jnp.float32)),
+        dimension_semantics=("parallel", "parallel"),
         interpret=interpret, name="rglru_decode_step",
-    )(u, gate, conv_state, h_state, conv_w.astype(jnp.float32), conv_b2,
-      rg_w, rgb2, ig_w, igb2, lam2)
-    return y, new_conv, new_h
+    )(u3, conv_state, conv_w32, conv_b2, u3, conv_state, conv_w32, conv_b2,
+      g3, h3, rg_w, rgb2, ig_w, igb2, lam2)
+    return y.reshape(b, wd), new_conv, new_h.reshape(b, wd)

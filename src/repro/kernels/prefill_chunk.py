@@ -46,6 +46,10 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import common
 
 Array = jax.Array
+# Scoped VMEM for one (batch row, chunk) cell: a 256-token mamba2-130m
+# chunk needs ~29 MiB (the (L, L) decay blocks, the conv window and the
+# double-buffered streams), above Mosaic's 16 MiB default.
+_PREFILL_VMEM_BYTES = 64 * 2 ** 20
 
 
 # ----------------------------------------------------------------------------
@@ -230,57 +234,70 @@ def _prefill_kernel(width: int, di: int, g: int, p: int, n: int,
         L = xbc.shape[0]
         win = jnp.concatenate([tail_scr[...], xbc], axis=0)  # (L+w-1, dxbc)
         w = cw_ref[...].astype(jnp.float32)                  # (w, dxbc)
-        conv = sum(win[i:i + L] * w[i] for i in range(width)) + \
+        conv = sum(win[i:i + L] * w[i:i + 1] for i in range(width)) + \
             cb_ref[...].astype(jnp.float32)
         # Stream-dtype rounding at the activation boundary (matches the
-        # unfused chain, so fused/unfused agree under bf16 params).
-        act = silu(conv.astype(sd))
+        # unfused chain, so fused/unfused agree under bf16 params); the
+        # activation itself evaluates in fp32 (Mosaic has no bf16 path
+        # for its constants).
+        act = silu(conv.astype(sd).astype(jnp.float32)).astype(sd)
         xs = act[:, :di]                                     # (L, di), sd
-        Bq = act[:, di:di + g * n].reshape(L, g, n).astype(jnp.float32)
-        Cq = act[:, di + g * n:].reshape(L, g, n).astype(jnp.float32)
+        Bq = act[:, di:di + g * n].astype(jnp.float32)       # (L, g*n)
+        Cq = act[:, di + g * n:].astype(jnp.float32)
         dt_f = softplus(dt_ref[0].astype(jnp.float32) +
                         dtb_ref[...].astype(jnp.float32))    # (L, h)
         a = dt_f * a_ref[...].astype(jnp.float32)            # (L, h)
-        tril = jnp.tril(jnp.ones((L, L), jnp.float32))
+        rows = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
+        cols = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
+        trilb = rows >= cols
+        tril = trilb.astype(jnp.float32)
         # CumBA: prefix sums for all heads as one (L, L) x (L, h) matmul.
-        cs = jnp.dot(tril, a, preferred_element_type=jnp.float32)
-        trilb = tril > 0
-        xdt = xs.astype(jnp.float32).reshape(L, h, p) * \
-            dt_f[..., None]                                  # (L, h, p)
-        state = st_scr[...]                                  # (h, p, n)
+        cs = common.dot(tril, a)                             # (L, h)
+        one = jnp.ones((1, 1), jnp.float32)
+        E = common.head_expand(h, p)                         # (h, di)
+        xdt = xs.astype(jnp.float32) * common.dot(dt_f, E)   # (L, di)
+        state = st_scr[...]                                  # (h*p, n)
+        # Whole-chunk decay of the incoming state, one row per channel:
+        # per-head totals expand to channels, then a K=1 outer product
+        # lays them down the sublanes.
+        chunk_decay = common.dot(jnp.exp(cs[L - 1:L, :]), E)  # (1, di)
+        state_decay = common.dot(chunk_decay, jnp.ones((1, n), jnp.float32),
+                                 ((0,), (0,)))               # (h*p, n)
 
         ys = []
         sts = []
         for gi in range(g):
-            Bg, Cg = Bq[:, gi], Cq[:, gi]                    # (L, n)
-            CB = jnp.dot(Cg, Bg.T, preferred_element_type=jnp.float32)
+            Bg = Bq[:, gi * n:(gi + 1) * n]                  # (L, n)
+            Cg = Cq[:, gi * n:(gi + 1) * n]
+            CB = common.dot(Cg, Bg, ((1,), (1,)))            # (L, L)
             for qi in range(hpg):
                 hi = gi * hpg + qi
-                cs_h = cs[:, hi]                             # (L,)
-                seg = cs_h[:, None] - cs_h[None, :]
+                cs_c = cs[:, hi:hi + 1]                      # (L, 1)
+                # The same sums as a row (an exact K=1 product), so the
+                # segment differences cancel on the diagonal.
+                cs_r = common.dot(one, cs_c, ((1,), (1,)))   # (1, L)
+                seg = cs_c - cs_r
                 dec = jnp.where(trilb,
                                 jnp.exp(jnp.where(trilb, seg, 0.0)), 0.0)
-                x_h = xdt[:, hi]                             # (L, p)
-                y_h = jnp.dot(CB * dec, x_h,
-                              preferred_element_type=jnp.float32)
-                y_h += jnp.dot(Cg, state[hi].T,
-                               preferred_element_type=jnp.float32) * \
-                    jnp.exp(cs_h)[:, None]
-                dst = jnp.exp(cs_h[-1] - cs_h)
-                st_h = jnp.exp(cs_h[-1]) * state[hi] + \
-                    jnp.dot((x_h * dst[:, None]).T, Bg,
-                            preferred_element_type=jnp.float32)
+                x_h = xdt[:, hi * p:(hi + 1) * p]            # (L, p)
+                st_h = state[hi * p:(hi + 1) * p]            # (p, n)
+                y_h = common.dot(CB * dec, x_h) + \
+                    common.dot(Cg, st_h, ((1,), (1,))) * jnp.exp(cs_c)
                 ys.append(y_h)
-                sts.append(st_h)
-        y = jnp.stack(ys, axis=1)                            # (L, h, p)
-        new_state = jnp.stack(sts, axis=0)                   # (h, p, n)
+                # This chunk's contribution to the outgoing state.
+                sts.append(common.dot(
+                    x_h * jnp.exp(cs_c[L - 1:L] - cs_c), Bg, ((0,), (0,))))
+        y = jnp.concatenate(ys, axis=1)                      # (L, di)
+        new_state = state * state_decay + \
+            jnp.concatenate(sts, axis=0)                     # (h*p, n)
 
-        y = y.astype(sd) + xs.reshape(L, h, p) * \
-            d_ref[...].astype(sd).reshape(h)[None, :, None]
-        yf = y.reshape(L, di).astype(jnp.float32)
+        d_ch = common.dot(d_ref[...].astype(jnp.float32), E)  # (1, di)
+        y = y.astype(sd) + xs * d_ch.astype(sd)
+        yf = y.astype(jnp.float32)
         ms = jnp.mean(yf * yf, axis=-1, keepdims=True)
         yn = yf * jax.lax.rsqrt(ms + eps) * ns_ref[...].astype(jnp.float32)
-        out = yn.astype(sd) * silu(z_ref[0])
+        out = yn.astype(sd) * \
+            silu(z_ref[0].astype(jnp.float32)).astype(sd)
 
         y_ref[0] = out.astype(y_ref.dtype)
         tail_scr[...] = win[L:]
@@ -303,7 +320,9 @@ def mamba2_prefill_pallas(z: Array, xbc: Array, dt: Array, conv_state: Array,
     Grid ``(batch, nchunks)`` with both axes "arbitrary": the sequential
     row-major walk lets VMEM scratch carry each row's conv tail and SSM
     state chunk-to-chunk; the state outputs revisit one block per batch
-    row, so only the final chunk's write leaves VMEM.
+    row, so only the final chunk's write leaves VMEM.  The SSM state
+    travels as ``(b, h*p, n)`` (the same bytes as ``(b, h, p, n)``): each
+    head's ``(p, n)`` block is then a sublane-aligned row slice.
     """
     b, l, di = z.shape
     g, p = ngroups, head_dim
@@ -326,7 +345,7 @@ def mamba2_prefill_pallas(z: Array, xbc: Array, dt: Array, conv_state: Array,
             pl.BlockSpec((1, chunk, dxbc), row),
             pl.BlockSpec((1, chunk, h), row),
             pl.BlockSpec((1, width - 1, dxbc), head),
-            pl.BlockSpec((1, h, p, n), lambda bi, ci: (bi, 0, 0, 0)),
+            pl.BlockSpec((1, h * p, n), head),
             pl.BlockSpec((width, dxbc), rep2),
             pl.BlockSpec((1, dxbc), rep2),
             pl.BlockSpec((1, h), rep2),
@@ -337,21 +356,22 @@ def mamba2_prefill_pallas(z: Array, xbc: Array, dt: Array, conv_state: Array,
         out_specs=[
             pl.BlockSpec((1, chunk, di), row),
             pl.BlockSpec((1, width - 1, dxbc), head),
-            pl.BlockSpec((1, h, p, n), lambda bi, ci: (bi, 0, 0, 0)),
+            pl.BlockSpec((1, h * p, n), head),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, l, di), z.dtype),
             jax.ShapeDtypeStruct((b, width - 1, dxbc), conv_state.dtype),
-            jax.ShapeDtypeStruct((b, h, p, n), jnp.float32),
+            jax.ShapeDtypeStruct((b, h * p, n), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((width - 1, dxbc), jnp.float32),
-            pltpu.VMEM((h, p, n), jnp.float32),
+            pltpu.VMEM((h * p, n), jnp.float32),
         ],
         dimension_semantics=("arbitrary", "arbitrary"),
+        vmem_limit_bytes=_PREFILL_VMEM_BYTES,
         interpret=interpret,
         name="mamba2_prefill",
-    )(z, xbc, dt, conv_state, ssm_state, conv_w,
+    )(z, xbc, dt, conv_state, ssm_state.reshape(b, h * p, n), conv_w,
       conv_b.reshape(1, dxbc), dt_bias.reshape(1, h), A.reshape(1, h),
       D.reshape(1, h), norm_scale.reshape(1, di))
-    return y, new_conv, new_ssm
+    return y, new_conv, new_ssm.reshape(b, h, p, n)

@@ -25,8 +25,6 @@ Array = jax.Array
 
 
 def _rg_lru_kernel(block_t: int):
-    del block_t
-
     def kernel(a_ref, b_ref, o_ref, h_ref):
         t = pl.program_id(2)
 
@@ -34,20 +32,16 @@ def _rg_lru_kernel(block_t: int):
         def _init():
             h_ref[...] = jnp.zeros_like(h_ref)
 
-        a = a_ref[0, :, :].astype(jnp.float32)   # (bt, bd)
-        b = b_ref[0, :, :].astype(jnp.float32)
+        # Serial VPU loop over the block's time rows; the carried state
+        # lives in the (1, bd) scratch across the sequential time axis.
+        def step(i, h):
+            row = pl.ds(i, 1)
+            h = a_ref[0, row, :].astype(jnp.float32) * h + \
+                b_ref[0, row, :].astype(jnp.float32)
+            o_ref[0, row, :] = h.astype(o_ref.dtype)
+            return h
 
-        def comb(c1, c2):
-            a1, b1 = c1
-            a2, b2 = c2
-            return a1 * a2, b1 * a2 + b2
-
-        # In-block log-depth scan (VPU), then fold in the carried state.
-        a_sc, b_sc = jax.lax.associative_scan(comb, (a, b), axis=0)
-        h_in = h_ref[0, :]                        # (bd,)
-        h_all = b_sc + a_sc * h_in[None, :]
-        o_ref[0, :, :] = h_all.astype(o_ref.dtype)
-        h_ref[0, :] = h_all[-1, :]
+        h_ref[...] = jax.lax.fori_loop(0, block_t, step, h_ref[...])
 
     return kernel
 

@@ -35,58 +35,72 @@ from repro.kernels import common
 Array = jax.Array
 
 
-def _ssd_chunk_kernel(x_ref, acum_ref, b_ref, c_ref, y_ref, st_ref):
-    cs = acum_ref[0, 0, ...].astype(jnp.float32)            # (1, L) row
-    cs = cs.reshape(-1)                                     # (L,)
-    x = x_ref[0, 0, :, 0, :].astype(jnp.float32)            # (L, p)
-    B = b_ref[0, 0, :, 0, :].astype(jnp.float32)            # (L, n)
-    C = c_ref[0, 0, :, 0, :].astype(jnp.float32)            # (L, n)
+def _ssd_chunk_kernel(x_ref, csr_ref, csc_ref, b_ref, c_ref, y_ref, st_ref):
+    cs_r = csr_ref[0, 0, 0].astype(jnp.float32)             # (1, L) row
+    cs_c = csc_ref[0, 0, 0].astype(jnp.float32)             # (L, 1) column
+    x = x_ref[0, 0, 0].astype(jnp.float32)                  # (L, p)
+    B = b_ref[0, 0, 0].astype(jnp.float32)                  # (L, n)
+    C = c_ref[0, 0, 0].astype(jnp.float32)                  # (L, n)
     L = x.shape[0]
 
     # CumBA segsum: S_ij = cs_i - cs_j, masked above the diagonal.
-    seg = cs[:, None] - cs[None, :]
-    tril = jnp.tril(jnp.ones((L, L), jnp.bool_))
+    rows = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
+    tril = rows >= cols
+    seg = cs_c - cs_r
     decay = jnp.where(tril, jnp.exp(jnp.where(tril, seg, 0.0)), 0.0)
 
-    scores = jnp.dot(C, B.T, preferred_element_type=jnp.float32)   # MXU
-    y = jnp.dot(scores * decay, x, preferred_element_type=jnp.float32)
-    y_ref[0, 0, :, 0, :] = y.astype(y_ref.dtype)
+    scores = common.dot(C, B, ((1,), (1,)))                 # MXU, C @ B^T
+    y = common.dot(scores * decay, x)
+    y_ref[0, 0, 0] = y.astype(y_ref.dtype)
 
     # Outgoing chunk state: sum_l B_l (exp(cs_last - cs_l)) x_l
-    dstate = jnp.exp(cs[-1] - cs)                            # (L,)
-    st = jnp.dot((x * dstate[:, None]).T, B,
-                 preferred_element_type=jnp.float32)         # (p, n)
-    st_ref[0, 0, 0, :, :] = st.astype(st_ref.dtype)
+    xw = x * jnp.exp(cs_c[L - 1:L] - cs_c)                  # (L, p)
+    st = common.dot(xw, B, ((0,), (0,)))                    # (p, n)
+    st_ref[0, 0, 0] = st.astype(st_ref.dtype)
 
 
 def ssd_chunk(x_c: Array, a_c: Array, A_cum: Array, B_c: Array, C_c: Array,
               *, interpret: bool = False):
-    """Run the fused intra-chunk pass. See module docstring for shapes."""
+    """Run the fused intra-chunk pass. See module docstring for shapes.
+
+    The kernel sees head- and group-major copies (``(b, c, h, L, p)``,
+    ``(b, c, g, L, n)``) and the prefix sums as both a row and a column,
+    so every block spans its array's last two dims and no in-kernel
+    transpose is needed."""
     b, c, L, h, p = x_c.shape
     g, n = B_c.shape[3], B_c.shape[4]
     hpg = h // g
+    f32 = jnp.float32
+    x_t = jnp.swapaxes(x_c.astype(f32), 2, 3)               # (b, c, h, L, p)
+    B_t = jnp.swapaxes(B_c.astype(f32), 2, 3)               # (b, c, g, L, n)
+    C_t = jnp.swapaxes(C_c.astype(f32), 2, 3)
+    cs = A_cum.astype(f32)
+    cs_row, cs_col = cs[..., None, :], cs[..., :, None]     # (b, h, c, ...)
 
-    grid = (b, c, h)
+    head = lambda bi, ci, hi: (bi, ci, hi, 0, 0)
+    grp = lambda bi, ci, hi: (bi, ci, hi // hpg, 0, 0)
+    hc = lambda bi, ci, hi: (bi, hi, ci, 0, 0)
     y, st = common.pallas_call(
         _ssd_chunk_kernel,
-        grid=grid,
+        grid=(b, c, h),
         in_specs=[
-            pl.BlockSpec((1, 1, L, 1, p), lambda bi, ci, hi: (bi, ci, 0, hi, 0)),
-            pl.BlockSpec((1, 1, 1, L), lambda bi, ci, hi: (bi, hi, ci, 0)),
-            pl.BlockSpec((1, 1, L, 1, n), lambda bi, ci, hi: (bi, ci, 0, hi // hpg, 0)),
-            pl.BlockSpec((1, 1, L, 1, n), lambda bi, ci, hi: (bi, ci, 0, hi // hpg, 0)),
+            pl.BlockSpec((1, 1, 1, L, p), head),
+            pl.BlockSpec((1, 1, 1, 1, L), hc),
+            pl.BlockSpec((1, 1, 1, L, 1), hc),
+            pl.BlockSpec((1, 1, 1, L, n), grp),
+            pl.BlockSpec((1, 1, 1, L, n), grp),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, L, 1, p), lambda bi, ci, hi: (bi, ci, 0, hi, 0)),
-            pl.BlockSpec((1, 1, 1, p, n), lambda bi, ci, hi: (bi, ci, hi, 0, 0)),
+            pl.BlockSpec((1, 1, 1, L, p), head),
+            pl.BlockSpec((1, 1, 1, p, n), head),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, c, L, h, p), jnp.float32),
-            jax.ShapeDtypeStruct((b, c, h, p, n), jnp.float32),
+            jax.ShapeDtypeStruct((b, c, h, L, p), f32),
+            jax.ShapeDtypeStruct((b, c, h, p, n), f32),
         ],
         dimension_semantics=("parallel", "parallel", "parallel"),
         interpret=interpret,
         name="ssd_chunk",
-    )(x_c.astype(jnp.float32), A_cum.astype(jnp.float32),
-      B_c.astype(jnp.float32), C_c.astype(jnp.float32))
-    return y, st
+    )(x_t, cs_row, cs_col, B_t, C_t)
+    return jnp.swapaxes(y, 2, 3), st

@@ -1,5 +1,9 @@
 import os
+# A fake-512-device CPU tool: pin this process and the per-cell children it
+# starts (which inherit os.environ) to the CPU, so none of them reaches for
+# an attached accelerator.
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
@@ -26,6 +30,10 @@ from pathlib import Path
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
+
+# Also holds where jax was imported before this module (python -m imports
+# the parent packages first): the backend has not been chosen yet.
+jax.config.update("jax_platforms", "cpu")
 
 from repro.configs import get_config, shapes as shp
 from repro.configs.registry import ASSIGNED, list_archs

@@ -8,16 +8,10 @@ import and then calls this.
 from __future__ import annotations
 
 import jax
-
-try:
-    from jax.sharding import AxisType
-except ImportError:     # older jax: meshes are implicitly Auto-typed
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _make(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 

@@ -21,12 +21,13 @@ from repro.core.xamba import DECODE_MODES, PREFILL_MODES, QUANT_MODES
 from repro.models import build_model
 from repro.nn import quant
 from repro.nn.params import init_params
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serve import ContinuousEngine, Engine, ServeConfig
 
 log = logging.getLogger("repro.serve")
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mamba2-130m")
     ap.add_argument("--reduced", action="store_true")
@@ -136,8 +137,11 @@ def main(argv=None):
                     help="JSONL file for flight-recorder fault dumps "
                          "(render with repro.launch.trace_report "
                          "--flight PATH)")
-    args = ap.parse_args(argv)
-    logging.basicConfig(level=logging.INFO, format="%(message)s")
+    return ap.parse_args(argv)
+
+
+def build_engine(args: argparse.Namespace):
+    """Model, seeded random params and the serving engine for ``args``."""
     if args.prefill_chunk and args.engine != "continuous":
         log.warning("--prefill-chunk only applies to --engine continuous; "
                     "the wave engine keeps monolithic bucketed prefill")
@@ -197,11 +201,14 @@ def main(argv=None):
                         if args.engine == "continuous" else 0),
         flight_path=args.flight_path)
     engine_cls = ContinuousEngine if args.engine == "continuous" else Engine
-    engine = engine_cls(model, params, scfg)
-
     if args.prefix_cache_mb and args.engine != "continuous":
         log.warning("--prefix-cache-mb only applies to --engine continuous")
+    return engine_cls(model, params, scfg)
 
+
+def submit_workload(engine, args: argparse.Namespace) -> None:
+    """Queue ``args.requests`` seeded random prompts."""
+    cfg = engine.model.cfg
     rng = np.random.default_rng(args.seed)
     shared = rng.integers(1, cfg.vocab_size, args.shared_prefix).tolist()
     for _ in range(args.requests):
@@ -213,10 +220,10 @@ def main(argv=None):
                        plen - plen % args.prefill_chunk)
         engine.submit(shared + rng.integers(1, cfg.vocab_size,
                                             plen).tolist())
-    try:
-        done = engine.run()
-    finally:
-        engine.close()
+
+
+def report(engine, done, args: argparse.Namespace) -> None:
+    """Log outputs, serving stats and the engine's health counters."""
     for r in done[:4]:
         log.info("req %d: %d prompt toks -> %s%s", r.uid, len(r.prompt),
                  r.out_tokens[:8], "..." if len(r.out_tokens) > 8 else "")
@@ -262,6 +269,19 @@ def main(argv=None):
         log.info("trace: %d events -> %s (+ %s); analyze with "
                  "python -m repro.launch.trace_report %s",
                  len(engine.tracer.events), args.trace, jsonl, args.trace)
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    logging.basicConfig(level=logging.INFO, format="%(message)s")
+    enable_compile_cache()
+    engine = build_engine(args)
+    submit_workload(engine, args)
+    try:
+        done = engine.run()
+    finally:
+        engine.close()
+    report(engine, done, args)
     return done
 
 

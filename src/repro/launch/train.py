@@ -26,6 +26,7 @@ from repro.models import build_model
 from repro.nn.params import init_params
 from repro.optim import AdamWConfig, ScheduleConfig
 from repro.runtime import StepMonitor
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.train import TrainConfig, make_train_step
 
 log = logging.getLogger("repro.train")
@@ -48,6 +49,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
     logging.basicConfig(level=logging.INFO, format="%(message)s")
+    enable_compile_cache()
 
     cfg = get_config(args.arch, reduced=args.reduced)
     model = build_model(cfg)

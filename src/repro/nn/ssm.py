@@ -201,8 +201,8 @@ def mamba2_apply(params: dict, cfg, x: Array,
                          if state is not None else None)
             return out, new_state
         # Fires once per compiled shape (trace-time), not per call.
-        log.info("fused prefill (%s) skipped: %s — running the unfused "
-                 "chain", pf_mode, reason)
+        log.warning("fused prefill (%s) skipped: %s — running the unfused "
+                    "chain", pf_mode, reason)
 
     silu = pwl.activation("silu", xamba)
     softplus = pwl.activation("softplus", xamba)

@@ -125,6 +125,9 @@ class ProgramCard:
     opcodes: int = 0
     copies: int = 0
     copy_bytes: int = 0
+    # Mosaic (Pallas TPU) kernels in the compiled program: 0 in interpret
+    # mode and wherever a kernel path ran the XLA chain instead.
+    tpu_kernels: int = 0
     compile_s: float = 0.0
     roofline: Dict[str, Any] = dataclasses.field(default_factory=dict)
     budget: Optional[ProgramBudget] = None
@@ -184,7 +187,8 @@ def build_card(name: str, program_id: str, fn, example_args,
     bytes_accessed = float(ca.get("bytes accessed", 0.0) or 0.0)
 
     mem = buffer_assignment_stats(compiled)
-    fp = op_fingerprint(compiled.as_text())
+    text = compiled.as_text()
+    fp = op_fingerprint(text)
     copies = fp.get("copy", {"count": 0, "bytes": 0})
 
     card = ProgramCard(
@@ -197,6 +201,7 @@ def build_card(name: str, program_id: str, fn, example_args,
         instructions=sum(v["count"] for v in fp.values()),
         opcodes=len(fp),
         copies=copies["count"], copy_bytes=copies["bytes"],
+        tpu_kernels=text.count('custom_call_target="tpu_custom_call"'),
         compile_s=round(compile_s, 4),
         roofline=roofline_terms(flops, bytes_accessed, 0.0, 0.0),
         budget=budget)
