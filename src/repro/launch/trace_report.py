@@ -5,9 +5,10 @@ records the span taxonomy of ``serve/tracing.py``; this tool folds a
 saved trace — Chrome JSON or the JSONL event log — into:
 
 * **per-phase wall breakdown** — where every microsecond of wall went:
-  decode / prefill / admission / snapshot moves / other host work / idle
-  gaps.  Self-times are computed by interval nesting on the engine
-  track (a span's children don't double-count), so the phase total must
+  decode / prefill programs / sampling (logits copy and host draw) /
+  admission / snapshot moves / other host work / idle gaps.  Self-times
+  are computed by interval nesting on the engine track (a span's
+  children don't double-count), so the phase total must
   reconcile with the trace's wall extent — ``--check`` fails the run if
   coverage drifts more than 5%.
 * **TTFT decomposition** — per request: queue wait (arrival ->
@@ -45,6 +46,8 @@ PHASE_OF = {
     "decode_step": "decode",
     "prefill_chunk": "prefill",
     "prefill_bucket": "prefill",
+    "logits_to_host": "sampling",
+    "sample": "sampling",
     "admit": "admission",
     "prefix_lookup": "admission",
     "snapshot_restore": "snapshot",

@@ -106,7 +106,6 @@ class ContinuousEngine(EngineBase):
 
     def __init__(self, model, params, cfg: ServeConfig, *,
                  draft_params=None):
-        super().__init__(model, params, cfg)
         self.slots = cfg.max_batch
         self.buckets = tuple(sorted(cfg.prefill_buckets))
         # Normalize "disabled" spellings (None and 0) to None so every
@@ -115,6 +114,9 @@ class ContinuousEngine(EngineBase):
         self.spec_k = int(getattr(cfg, "speculate_k", 0) or 0)
         if self.spec_k < 0:
             raise ValueError(f"speculate_k must be >= 0, got {self.spec_k}")
+        # Set before the base builds the programs: chunk and spec_k decide
+        # which of them exist (_build_programs).
+        super().__init__(model, params, cfg)
         # One static cache length covers every tenant a slot can host; with
         # chunked prefill the longest padded prompt can overshoot the
         # largest bucket by up to chunk-1 pad tokens.  A speculative burst
@@ -160,13 +162,6 @@ class ContinuousEngine(EngineBase):
                                     tracer=self.tracer)
             self._bpool = StatePool(model, self.slots, self.max_seq, dtype,
                                     tracer=self.tracer)
-            self._verify = jax.jit(
-                lambda p, toks, cache, off:
-                model.verify_chunk(p, toks, cache, off),
-                donate_argnums=(2,))
-            self.sentinels["verify"] = RecompileSentinel(
-                "verify", self._verify,
-                strict=getattr(cfg, "strict_recompile", False))
             # Rolled-back rows park their already-emitted tokens here and
             # re-consume them through the ordinary decode step (one per
             # poll); bursts only run when every live row has drained.
@@ -201,13 +196,6 @@ class ContinuousEngine(EngineBase):
             # decode capacity.
             self._ppool = StatePool(model, self.slots, self.max_seq, dtype,
                                     tracer=self.tracer)
-            self._chunk_step = jax.jit(
-                lambda p, toks, cache, off:
-                model.prefill_chunk(p, toks, cache, off),
-                donate_argnums=(2,))
-            self.sentinels["prefill_chunk"] = RecompileSentinel(
-                "prefill_chunk", self._chunk_step,
-                strict=getattr(cfg, "strict_recompile", False))
             self._pref_req: List[Optional[Request]] = [None] * self.slots
             self._pref_toks: List[Optional[np.ndarray]] = [None] * self.slots
             self._pref_off = np.zeros(self.slots, np.int32)
@@ -335,13 +323,32 @@ class ContinuousEngine(EngineBase):
     # ------------------------------------------------------------------
     # program registry (docs/observability.md)
     # ------------------------------------------------------------------
+    def _build_programs(self) -> None:
+        """The base's ``decode`` and ``prefill`` plus, when configured,
+        ``prefill_chunk`` (modules ``jit_prefill_chunk``) and ``verify``
+        (``jit_verify``); the chunk and verify programs donate the pool
+        arena they advance."""
+        super()._build_programs()
+        model = self.model
+        if self.chunk:
+            def prefill_chunk(p, toks, cache, off):
+                return model.prefill_chunk(p, toks, cache, off)
+
+            self._chunk_step = self._program(prefill_chunk,
+                                             donate_argnums=(2,))
+        if self.spec_k:
+            def verify(p, toks, cache, off):
+                return model.verify_chunk(p, toks, cache, off)
+
+            self._verify = self._program(verify, donate_argnums=(2,))
+
     def _register_programs(self) -> None:
         """(Re)attach every compiled program this engine warms up to the
         registry at its serve shapes.  Cheap — ShapeDtypeStructs only, no
         compiles — and re-run by a backend rebuild so program cards
         always lower the jits currently serving.  Ids are stable across
-        re-registration; sentinels pick up their program ids here so a
-        recompile trip names the program, not just a span label."""
+        re-registration, so a sentinel's program id (given when
+        ``_program`` built it) keeps naming the same program."""
         reg = self.registry
         i32 = jnp.int32
         tok = jax.ShapeDtypeStruct((self.slots, 1), i32)
@@ -409,13 +416,9 @@ class ContinuousEngine(EngineBase):
                      example_args=(pool.cache, scalar))
         reg.register("pool_reset", fn_thunk=pool_op("_reset"),
                      example_args=(pool.cache, scalar))
-        # Sentinels: existing ones learn their program id; the lazily
-        # -built pool ops get inert-until-first-sight sentinels of their
-        # own (fn_getter reads size -1 until the op exists).
+        # The lazily-built pool ops get inert-until-first-sight sentinels
+        # of their own (fn_getter reads size -1 until the op exists).
         strict = getattr(self.cfg, "strict_recompile", False)
-        for name, s in self.sentinels.items():
-            if name in reg:
-                s.program_id = reg.program_id(name)
         for name, attr in (("pool_insert", "_insert"),
                            ("pool_reset", "_reset")):
             if name not in self.sentinels:
@@ -501,41 +504,16 @@ class ContinuousEngine(EngineBase):
         from repro.models.registry import build_model
         model = build_model(self.model.cfg.with_decode_mode(mode))
         self.model = model
-        strict = getattr(self.cfg, "strict_recompile", False)
-        self._decode_params = getattr(model, "decode_view",
-                                      lambda p: p)(self.params)
-        self._prefill = jax.jit(
-            lambda p, batch, cache: model.prefill(p, batch, cache))
-        self._decode = jax.jit(
-            lambda p, tok, cache, idx: model.decode_step(p, tok, cache, idx),
-            donate_argnums=(2,))
-        self.sentinels["decode"] = RecompileSentinel("decode", self._decode,
-                                                     strict=strict)
-        self.sentinels["prefill"] = RecompileSentinel("prefill",
-                                                      self._prefill,
-                                                      strict=strict)
+        self._build_programs()
         for pool in (self.pool,
                      getattr(self, "_ppool", None),
                      getattr(self, "_dpool", None),
                      getattr(self, "_bpool", None)):
             if pool is not None:
                 pool.model = model  # snapshot export/import path
-        if self.chunk:
-            self._chunk_step = jax.jit(
-                lambda p, toks, cache, off:
-                model.prefill_chunk(p, toks, cache, off),
-                donate_argnums=(2,))
-            self.sentinels["prefill_chunk"] = RecompileSentinel(
-                "prefill_chunk", self._chunk_step, strict=strict)
         if self.spec_k:
             self._draft_params = getattr(model, "decode_view",
                                          lambda p: p)(self._raw_draft)
-            self._verify = jax.jit(
-                lambda p, toks, cache, off:
-                model.verify_chunk(p, toks, cache, off),
-                donate_argnums=(2,))
-            self.sentinels["verify"] = RecompileSentinel(
-                "verify", self._verify, strict=strict)
         if self._state_probe is not None:
             self._state_probe = self._build_state_probe()
         # The rebuilt jits replace the registry's lowering recipes (same
@@ -950,26 +928,30 @@ class ContinuousEngine(EngineBase):
             for row, (_, req) in enumerate(group):
                 p = req.prompt[-bucket:]
                 tokens[row, bucket - len(p):] = p
-            t0 = time.perf_counter()
-            toks_dev = jnp.asarray(tokens)
-            logits, cache = self._guarded_call(
-                "prefill",
-                lambda: self._prefill(self.params, {"tokens": toks_dev},
-                                      self._scratch))
+            with self.tracer.span("prefill_bucket", bucket=bucket,
+                                  rows=len(group),
+                                  tokens=bucket * len(group),
+                                  program=self._pid_prefill):
+                t0 = time.perf_counter()
+                toks_dev = jnp.asarray(tokens)
+                logits, cache = self._guarded_call(
+                    "prefill",
+                    lambda: self._prefill(self.params, {"tokens": toks_dev},
+                                          self._scratch))
+                jax.block_until_ready(logits)
+                t1 = time.perf_counter()
+            self._observe_step(self.monitor_prefill, "prefill", t1 - t0)
+            self.metrics.record_prefill(bucket * len(group), t1 - t0)
+            lg = self._logits_to_host(logits, "prefill")
             # First tokens sample at position = bucket (tokens consumed so
             # far), keyed per owning request — see _sample_rows.
             uids = np.zeros(self.slots, np.int64)
             for row, (_, req) in enumerate(group):
                 uids[row] = req.uid
-            first = self._sample_rows(logits, uids,
-                                      np.full(self.slots, bucket, np.int64))
-            t1 = time.perf_counter()
-            self.tracer.complete("prefill_bucket", t0, t1, bucket=bucket,
-                                 rows=len(group),
-                                 tokens=bucket * len(group),
-                                 program=self._pid_prefill)
-            self._observe_step(self.monitor_prefill, "prefill", t1 - t0)
-            self.metrics.record_prefill(bucket * len(group), t1 - t0)
+            with self.tracer.span("sample", phase="prefill", rows=self.slots,
+                                  used=len(group)):
+                first = self._sample_rows(
+                    lg, uids, np.full(self.slots, bucket, np.int64))
             self.pool.insert_rows(cache,
                                   [row for row in range(len(group))],
                                   [slot for slot, _ in group])
@@ -1113,21 +1095,20 @@ class ContinuousEngine(EngineBase):
         for i in rows:
             off = self._pref_off[i]
             tokens[i] = self._pref_toks[i][off:off + C]
-        t0 = time.perf_counter()
-        toks_dev = jnp.asarray(tokens)
-        off_dev = jnp.asarray(self._pref_off)
-        logits, self._ppool.cache = self._guarded_call(
-            "prefill_chunk",
-            lambda: self._chunk_step(self.params, toks_dev,
-                                     self._ppool.cache, off_dev))
-        # Synchronize before the host-side bookkeeping so the recorded
-        # chunk time is the compiled call alone — snapshot exports and
-        # sampling get their own spans (phase attribution stays honest).
-        jax.block_until_ready(logits)
-        t1 = time.perf_counter()
-        self.tracer.complete("prefill_chunk", t0, t1, rows=len(rows),
-                             tokens=C * len(rows),
-                             program=self._pid_chunk)
+        with self.tracer.span("prefill_chunk", rows=len(rows),
+                              tokens=C * len(rows), program=self._pid_chunk):
+            t0 = time.perf_counter()
+            toks_dev = jnp.asarray(tokens)
+            off_dev = jnp.asarray(self._pref_off)
+            logits, self._ppool.cache = self._guarded_call(
+                "prefill_chunk",
+                lambda: self._chunk_step(self.params, toks_dev,
+                                         self._ppool.cache, off_dev))
+            # Synchronize before the host-side bookkeeping so the recorded
+            # chunk time is the compiled call alone — snapshot exports,
+            # the logits copy and sampling get their own spans.
+            jax.block_until_ready(logits)
+            t1 = time.perf_counter()
         self._observe_step(self.monitor_prefill, "prefill", t1 - t0)
         self.metrics.record_prefill(C * len(rows), t1 - t0)
         done_rows = []
@@ -1139,12 +1120,14 @@ class ContinuousEngine(EngineBase):
                 if self._pcache is not None:
                     self._prefix_release(i)
                 done_rows.append(i)
+        if done_rows:
+            # The hand-off's one copy: the probe and the sampler read it.
+            lg = self._logits_to_host(logits, "prefill")
         if done_rows and self._poison_probe != "off":
             # Gate the staging->decode handoff: a non-finite final-chunk
             # logits row means the staged state is poisoned — shed it here
             # so it never reaches the decode pool (or the prefix cache,
             # whose inserts are separately gated in _prefix_insert).
-            lg = np.asarray(logits, np.float32)
             now_p = time.time()
             kept = []
             for i in done_rows:
@@ -1180,7 +1163,9 @@ class ContinuousEngine(EngineBase):
             for i in done_rows:
                 uids[i] = self._pref_req[i].uid
                 poss[i] = len(self._pref_toks[i])
-            first = self._sample_rows(logits, uids, poss)
+            with self.tracer.span("sample", phase="prefill", rows=self.slots,
+                                  used=len(done_rows)):
+                first = self._sample_rows(lg, uids, poss)
             # Row i prefilled in the second pool becomes slot i's decode
             # state (same index — the slot was reserved at admission).
             self.pool.insert_rows(self._ppool.cache, done_rows, done_rows)
@@ -1380,22 +1365,25 @@ class ContinuousEngine(EngineBase):
                 not any(self._overflow[i] for i in live):
             self._spec_burst(live)
         elif live:
-            t0 = time.perf_counter()
-            tok_dev = jnp.asarray(self._next_tok[:, None])
-            pos_dev = jnp.asarray(self._pos)
-            logits, cache = self._guarded_call(
-                "decode",
-                lambda: self._decode(self._decode_params, tok_dev,
-                                     self.pool.cache, pos_dev))
-            lg = np.asarray(logits, np.float32)
-            nxt = self._sample_rows(lg, self._row_uids(), self._pos + 1)
-            self.pool.cache = cache
-            t1 = time.perf_counter()
-            self.tracer.complete("decode_step", t0, t1, live=len(live),
-                                 tokens=len(live),
-                                 program=self._pid_decode)
+            # The program span ends when the logits are ready; the copy to
+            # the host and the draw are its siblings under ``poll``.
+            with self.tracer.span("decode_step", live=len(live),
+                                  tokens=len(live), program=self._pid_decode):
+                t0 = time.perf_counter()
+                tok_dev = jnp.asarray(self._next_tok[:, None])
+                pos_dev = jnp.asarray(self._pos)
+                logits, self.pool.cache = self._guarded_call(
+                    "decode",
+                    lambda: self._decode(self._decode_params, tok_dev,
+                                         self.pool.cache, pos_dev))
+                jax.block_until_ready(logits)
+                t1 = time.perf_counter()
             self._observe_step(self.monitor_decode, "decode", t1 - t0)
             self.metrics.record_step(len(live), t1 - t0)
+            lg = self._logits_to_host(logits, "decode")
+            with self.tracer.span("sample", phase="decode", rows=self.slots,
+                                  used=len(live)):
+                nxt = self._sample_rows(lg, self._row_uids(), self._pos + 1)
             # Dead slots decode into a sink: their position pins to the last
             # cache column until a refill overwrites the whole row.
             self._pos = np.minimum(self._pos + 1, self.max_seq - 1)

@@ -162,20 +162,6 @@ class EngineBase:
         self.model = model
         self.params = params
         self.cfg = cfg
-        # One-time pre-sliced view of stacked layer weights for the decode
-        # program (zero per-step weight copies); prefill keeps the stacked
-        # layout (scan-over-layers, one trace).
-        self._decode_params = getattr(model, "decode_view",
-                                      lambda p: p)(params)
-        self._prefill = jax.jit(
-            lambda p, batch, cache: model.prefill(p, batch, cache))
-        # The cache pytree is DONATED into the decode program: every step
-        # updates slot state in place (zero per-step state copies) while
-        # the compile-once discipline keeps the program count at one.
-        # (Prefill must NOT donate: its input cache is a reused scratch.)
-        self._decode = jax.jit(
-            lambda p, tok, cache, idx: model.decode_step(p, tok, cache, idx),
-            donate_argnums=(2,))
         self.tracer = Tracer() if getattr(cfg, "trace", None) else NULL_TRACER
         self._scheduler = Scheduler(getattr(cfg, "policy", "fcfs"),
                                     tracer=self.tracer)
@@ -191,20 +177,49 @@ class EngineBase:
         # wave engine registers decode/prefill name-only (its shapes
         # vary per wave); the continuous engine attaches example shapes.
         self.registry = ProgramRegistry()
-        self.registry.register("decode", self._decode)
-        self.registry.register("prefill", self._prefill)
         # Compile-once discipline as first-class sentinels: checked every
         # poll/wave, re-armed by reset_stats() (i.e. after warmup), so a
         # trip always means a *post-warmup* retrace.
-        strict = getattr(cfg, "strict_recompile", False)
-        self.sentinels = {
-            "decode": RecompileSentinel(
-                "decode", self._decode, strict=strict,
-                program_id=self.registry.program_id("decode")),
-            "prefill": RecompileSentinel(
-                "prefill", self._prefill, strict=strict,
-                program_id=self.registry.program_id("prefill")),
-        }
+        self.sentinels: Dict[str, RecompileSentinel] = {}
+        self._build_programs()
+
+    def _build_programs(self) -> None:
+        """(Re)build every compiled program over ``self.model`` — at
+        construction and on a backend rebuild.  Each program jits a
+        function named after its registry name, so its XLA module is
+        ``jit_<name>`` (``jit_decode``, ``jit_prefill``) and a device
+        profile tells the programs apart."""
+        model = self.model
+        # One-time pre-sliced view of stacked layer weights for the decode
+        # program (zero per-step weight copies); prefill keeps the stacked
+        # layout (scan-over-layers, one trace).
+        self._decode_params = getattr(model, "decode_view",
+                                      lambda p: p)(self.params)
+
+        def decode(p, tok, cache, idx):
+            return model.decode_step(p, tok, cache, idx)
+
+        def prefill(p, batch, cache):
+            return model.prefill(p, batch, cache)
+
+        # The cache pytree is DONATED into the decode program: every step
+        # updates slot state in place (zero per-step state copies) while
+        # the compile-once discipline keeps the program count at one.
+        # (Prefill must NOT donate: its input cache is a reused scratch.)
+        self._decode = self._program(decode, donate_argnums=(2,))
+        self._prefill = self._program(prefill)
+
+    def _program(self, fn, **jit_kw):
+        """Jit ``fn`` as the program named ``fn.__name__``: registered
+        name-only (an engine that knows its serve shapes attaches them)
+        and watched by a fresh recompile sentinel."""
+        name = fn.__name__
+        jitted = jax.jit(fn, **jit_kw)
+        self.registry.register(name, jitted)
+        self.sentinels[name] = RecompileSentinel(
+            name, jitted, strict=getattr(self.cfg, "strict_recompile", False),
+            program_id=self.registry.program_id(name))
+        return jitted
 
     def _buckets(self) -> Sequence[int]:
         return self.cfg.prefill_buckets
@@ -232,6 +247,13 @@ class EngineBase:
             buckets=self._buckets(), metrics=self.metrics)
         self._scheduler.submit(req)
         return req.uid
+
+    def _logits_to_host(self, logits, phase: str) -> np.ndarray:
+        """A program's logits as a host float32 array, copied under the
+        ``logits_to_host`` span (``phase``: ``decode`` | ``prefill``)."""
+        with self.tracer.span("logits_to_host", phase=phase,
+                              rows=logits.shape[0]):
+            return np.asarray(logits, np.float32)
 
     def _sample(self, logits) -> np.ndarray:
         out = sampling.sample(np.asarray(logits, np.float32),
@@ -353,7 +375,11 @@ class Engine(EngineBase):
             logits, cache = self._prefill(self.params,
                                           {"tokens": jnp.asarray(tokens)},
                                           pool.cache)
-            next_tok = self._sample(np.asarray(logits, np.float32))
+            jax.block_until_ready(logits)
+        lg = self._logits_to_host(logits, "prefill")
+        with self.tracer.span("sample", phase="prefill", rows=b,
+                              used=len(wave)):
+            next_tok = self._sample(lg)
 
         def finish(r: Request, slot: int) -> None:
             r.done = True
@@ -386,16 +412,19 @@ class Engine(EngineBase):
         for t in range(1, max_new):
             if not alive[:len(wave)].any():
                 break
-            ts0 = time.perf_counter()
-            tok = jnp.asarray(next_tok[:, None])
-            logits, cache = self._decode(self._decode_params, tok, cache,
-                                         jnp.int32(bucket + t - 1))
-            next_tok = self._sample(np.asarray(logits, np.float32))
-            ts1 = time.perf_counter()
-            self.tracer.complete("decode_step", ts0, ts1,
-                                 live=int(alive[:len(wave)].sum()))
-            self.metrics.record_step(int(alive[:len(wave)].sum()),
-                                     ts1 - ts0)
+            n_live = int(alive[:len(wave)].sum())
+            with self.tracer.span("decode_step", live=n_live):
+                ts0 = time.perf_counter()
+                tok = jnp.asarray(next_tok[:, None])
+                logits, cache = self._decode(self._decode_params, tok,
+                                             cache, jnp.int32(bucket + t - 1))
+                jax.block_until_ready(logits)
+                ts1 = time.perf_counter()
+            self.metrics.record_step(n_live, ts1 - ts0)
+            lg = self._logits_to_host(logits, "decode")
+            with self.tracer.span("sample", phase="decode", rows=b,
+                                  used=n_live):
+                next_tok = self._sample(lg)
             for i, r in enumerate(wave):
                 if alive[i] and len(r.out_tokens) < r.max_new_tokens:
                     r.emit(int(next_tok[i]))

@@ -14,12 +14,19 @@ instrumented with:
   slot); gauges are counter events (``ph: "C"``); one-off facts are
   instants (``ph: "i"``).  ``launch/trace_report.py`` folds a saved
   trace into phase breakdowns, TTFT decompositions, and slot timelines.
+  While enabled, every ``span`` also enters a
+  ``jax.profiler.TraceAnnotation`` of its name, so under a running
+  profiler the engine's spans sit on the host timeline beside the
+  device's operations, on one clock.
 * :class:`NullTracer` — the default.  Every method is a no-op and
-  ``span`` returns a shared do-nothing context manager, so the
-  instrumented hot path costs a few dict builds and attribute lookups
-  per *engine poll* (each poll contains at least one multi-millisecond
-  compiled call; ``benchmarks/bench_serve_continuous.bench_phase``
-  measures and asserts the end-to-end overhead of tracing at <= 2%).
+  ``span`` returns a shared do-nothing context manager (no profiler
+  annotation), so the instrumented hot path costs a few dict builds
+  and attribute lookups per *engine poll*.  What the *enabled* tracer
+  costs is measured on the chip (``docs/observability.md``, Overhead);
+  ``benchmarks/bench_serve_continuous.bench_phase`` times it on XLA-CPU
+  at a reduced size, where its pair-to-pair noise band (about -23% to
+  +13% in the committed ``BENCH_serve.json``) is far wider than the 2%
+  it asserts, so that bench bounds only gross regressions.
 * :class:`RecompileSentinel` — the compile-once discipline as a
   first-class check instead of an ad-hoc counter-string diff: it arms on
   a jitted callable's current cache size and counts every later growth
@@ -38,6 +45,8 @@ Span taxonomy (see ``docs/observability.md`` for the full table):
 ``prefill_bucket``  one monolithic bucketed prefill program call
 ``prefill_chunk``   one chunked-prefill program call (all staging rows)
 ``decode_step``     one decode program call across all slots
+``logits_to_host``  a program's logits copied to a host float32 array
+``sample``          one host draw of next tokens from those logits
 ``pool_insert``/``pool_reset``  state-pool row scatter / zero
 ``host_gap``        time between polls (host track — idle + caller)
 ``queue``           per-request: arrival -> admission (queue track)
@@ -51,6 +60,8 @@ import json
 import time
 from typing import Any, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 # Track ("tid") layout inside the single serve process ("pid" 0).
 TID_ENGINE = 0      # compiled-program calls + host scheduling sections
 TID_HOST = 1        # gaps between polls (idle / caller time)
@@ -61,13 +72,14 @@ _TRACK_NAMES = {TID_ENGINE: "engine", TID_HOST: "host", TID_QUEUE: "queue"}
 
 
 class _Span:
-    """Context manager recording one complete event on ``__exit__``.
+    """Context manager recording one complete event on ``__exit__``,
+    inside a profiler annotation of the same name.
 
     ``args`` stays mutable until exit so callers can attach facts they
     only learn mid-span (e.g. how many requests an ``admit`` admitted).
     """
 
-    __slots__ = ("_tr", "name", "tid", "args", "_t0")
+    __slots__ = ("_tr", "name", "tid", "args", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, tid: int,
                  args: Dict[str, Any]):
@@ -77,12 +89,16 @@ class _Span:
         self.args = args
 
     def __enter__(self) -> "_Span":
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
-        self._tr.complete(self.name, self._t0, time.perf_counter(),
-                          tid=self.tid, **self.args)
+        t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        self._tr.complete(self.name, self._t0, t1, tid=self.tid,
+                          **self.args)
 
 
 class _NullSpan:
@@ -142,8 +158,8 @@ class Tracer(NullTracer):
     into the old generations and accelerate the collector's generational
     clock until a full gen-2 pass lands *inside* the serve drain (~100ms
     with jax's heap resident — an 8%+ wall hit on ``bench_phase``).
-    Tuples of atoms get untracked at the first young collection, keeping
-    the traced hot loop within the <= 2% overhead budget."""
+    Tuples of atoms get untracked at the first young collection, which
+    keeps those full passes out of the traced drain."""
 
     enabled = True
 

@@ -481,3 +481,181 @@ def test_reset_stats_resets_observability():
     assert eng.metrics.completed == 0
     assert all(s.trips == 0 for s in eng.sentinels.values())
     assert eng.monitor_decode.records == []
+
+
+# ---------------------------------------------------------------------------
+# host round trip: program spans end at ready logits; the copy and the draw
+# are their siblings under poll
+# ---------------------------------------------------------------------------
+PROGRAM_SPANS = ("decode_step", "prefill_chunk", "prefill_bucket")
+HOST_SPANS = ("logits_to_host", "sample")
+
+
+def _engine_spans(events):
+    return [e for e in events if e["ph"] == "X" and e["tid"] == TID_ENGINE]
+
+
+def _inside(inner, outer, tol=1e-3):
+    return (outer["ts"] - tol <= inner["ts"] and
+            inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"] + tol)
+
+
+def _overlap_us(a, b):
+    return (min(a["ts"] + a["dur"], b["ts"] + b["dur"]) -
+            max(a["ts"], b["ts"]))
+
+
+def _assert_host_spans_outside_programs(events):
+    spans = _engine_spans(events)
+    progs = [e for e in spans if e["name"] in PROGRAM_SPANS]
+    hosts = [e for e in spans if e["name"] in HOST_SPANS]
+    assert progs and hosts
+    for h in hosts:
+        for p in progs:
+            assert _overlap_us(h, p) <= 1e-3, (h, p)
+
+
+def test_turnover_poll_emits_host_round_trip_siblings():
+    """One traced chunked poll in which a prompt finishes next to a
+    decoding request: the program spans, ``logits_to_host`` and
+    ``sample`` are siblings under ``poll``, with the rows each draws and
+    the rows whose token is kept."""
+    model, params = _model_params()
+    slots = 4
+    eng = ContinuousEngine(model, params, ServeConfig(
+        max_batch=slots, prefill_buckets=(16,), max_new_tokens=8,
+        prefill_chunk=8, temperature=1.0, trace=True))
+    try:
+        eng.submit([3, 1, 4, 1, 5])
+        while not any(r is not None for r in eng._slot_req):
+            eng.poll()
+        eng.submit([2, 7, 1, 8])            # one chunk: done in one poll
+        eng.tracer.reset()
+        eng.poll()
+        spans = _engine_spans(eng.tracer.events)
+    finally:
+        eng.close()
+    (poll,) = [e for e in spans if e["name"] == "poll"]
+    kids = [e for e in spans if e is not poll]
+    names = [e["name"] for e in kids]
+    for name in ("prefill_chunk", "decode_step", "logits_to_host", "sample"):
+        assert name in names, names
+    for e in kids:
+        assert _inside(e, poll), e
+        if e["name"] in PROGRAM_SPANS + HOST_SPANS:
+            # no engine span other than poll encloses a sibling
+            assert not [o for o in kids if o is not e and _inside(e, o)
+                        and o["dur"] > e["dur"]], e
+    _assert_host_spans_outside_programs(spans)
+
+    by_phase = {(e["name"], e["args"]["phase"]): e["args"] for e in kids
+                if e["name"] in HOST_SPANS}
+    assert by_phase[("sample", "prefill")] == {"phase": "prefill",
+                                               "rows": slots, "used": 1}
+    assert by_phase[("sample", "decode")] == {"phase": "decode",
+                                              "rows": slots, "used": 2}
+    assert by_phase[("logits_to_host", "prefill")]["rows"] == slots
+    assert by_phase[("logits_to_host", "decode")]["rows"] == slots
+    (dec,) = [e for e in kids if e["name"] == "decode_step"]
+    assert dec["args"]["live"] == 2
+    # the copy follows its program, and the draw follows the copy
+    order = [(e["name"], e["args"].get("phase")) for e in
+             sorted(kids, key=lambda e: e["ts"])
+             if e["name"] in ("prefill_chunk", "decode_step") + HOST_SPANS]
+    assert order == [("prefill_chunk", None), ("logits_to_host", "prefill"),
+                     ("sample", "prefill"), ("decode_step", None),
+                     ("logits_to_host", "decode"), ("sample", "decode")]
+
+
+@pytest.mark.parametrize("chunk", [None, 8], ids=["bucket", "chunked"])
+def test_program_spans_hold_no_host_round_trip(chunk):
+    """Over a whole traced drain, no ``logits_to_host`` or ``sample``
+    interval overlaps a program span, monolithic or chunked, and the
+    phase report still reconciles."""
+    model, params = _model_params()
+    eng, _ = _run_engine(model, params, trace=True, prefill_chunk=chunk)
+    events = eng.tracer.events
+    _assert_host_spans_outside_programs(events)
+    phases = {e["args"]["phase"] for e in _engine_spans(events)
+              if e["name"] == "sample"}
+    assert phases == {"prefill", "decode"}
+    rep = trace_report.analyze(events)
+    assert trace_report.check(rep) == [], trace_report.check(rep)
+    assert rep["phase_breakdown"]["phases_s"]["sampling"] > 0
+
+
+@pytest.mark.parametrize("chunk", [None, 8], ids=["bucket", "chunked"])
+def test_traced_and_untraced_tokens_identical_under_temperature(chunk):
+    model, params = _model_params()
+    _, off = _run_engine(model, params, trace=None, prefill_chunk=chunk,
+                         temperature=1.0, seed=3)
+    _, on = _run_engine(model, params, trace=True, prefill_chunk=chunk,
+                        temperature=1.0, seed=3)
+    assert on == off
+    assert len(off) == 4 and all(len(t) == 4 for t in off.values())
+
+
+def test_program_modules_are_named_after_registry_names():
+    """Each program's XLA module is ``jit_<registry name>``, also after a
+    backend rebuild swaps the jits."""
+    model, params = _model_params()
+    slots, chunk = 2, 8
+    eng = ContinuousEngine(model, params, ServeConfig(
+        max_batch=slots, prefill_buckets=(16,), max_new_tokens=4,
+        prefill_chunk=chunk))
+    tok = jnp.zeros((slots, 1), jnp.int32)
+    pos = jnp.zeros(slots, jnp.int32)
+
+    def texts():
+        return {
+            "decode": eng._decode.lower(eng._decode_params, tok,
+                                        eng.pool.cache, pos).as_text(),
+            "prefill": eng._prefill.lower(
+                eng.params, {"tokens": jnp.zeros((slots, 16), jnp.int32)},
+                eng._scratch).as_text(),
+            "prefill_chunk": eng._chunk_step.lower(
+                eng.params, jnp.zeros((slots, chunk), jnp.int32),
+                eng._ppool.cache, pos).as_text(),
+        }
+
+    def check(when):
+        for name, text in texts().items():
+            assert f"@jit_{name} " in text, (when, name)
+            assert "jit__lambda" not in text, (when, name)
+            assert eng.sentinels[name].program_id == \
+                eng.registry.program_id(name), (when, name)
+
+    try:
+        check("built")
+        eng._rebuild_backend("naive")
+        check("rebuilt")
+    finally:
+        eng.close()
+
+
+def test_tracer_span_enters_profiler_annotation(monkeypatch):
+    from repro.serve import tracing
+    seen = []
+
+    class FakeAnnotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            seen.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            seen.append(("exit", self.name))
+
+    monkeypatch.setattr(tracing, "TraceAnnotation", FakeAnnotation)
+    with NullTracer().span("decode_step", live=1):
+        pass
+    assert seen == []
+    tr = Tracer()
+    with tr.span("poll"):
+        with tr.span("sample", phase="decode", rows=2, used=1):
+            pass
+    assert seen == [("enter", "poll"), ("enter", "sample"),
+                    ("exit", "sample"), ("exit", "poll")]
+    assert [e["name"] for e in tr.events if e["ph"] == "X"] == \
+        ["sample", "poll"]
