@@ -7,8 +7,18 @@ cumsum; the scan itself is a per-channel linear recurrence
 
 which we implement three ways:
 
-* ``associative``  — ``jax.lax.associative_scan`` (log-depth, XLA),
-* ``sequential``   — ``jax.lax.scan`` oracle (exact reference),
+* ``sequential``   — the default for prefill, serving and training: a
+                     ``jax.lax.scan`` over time whose carry is the
+                     ``(b, d, n)`` state, held as ``(b, n, d)`` so
+                     ``d_inner`` lies on the lanes.  Each step forms its
+                     own decay and input, so nothing of size
+                     ``(b, l, d, n)`` is built; it is exact in fp32 and the
+                     oracle the other two are tested against.
+* ``associative``  — ``jax.lax.associative_scan`` (log-depth, XLA).  Not
+                     the default: its decay, input and up/down-sweep
+                     partials are fp32 ``(b, l, d, n)`` arrays in HBM, and
+                     with a decay that varies per ``(d, n)`` every step
+                     there is no matmul for the MXU to win that back.
 * ``chunked``      — CumBA-style: within a chunk the decay products
                      ``prod_{k=j+1..t} a_k = exp(segsum(log a))`` are the same
                      1-semiseparable structure SSD uses, so the intra-chunk
@@ -42,7 +52,7 @@ Array = jax.Array
 
 def selective_scan(u: Array, delta: Array, A: Array, B: Array, C: Array,
                    D: Optional[Array] = None, *,
-                   mode: str = "associative",
+                   mode: str = "sequential",
                    chunk_size: int = 128,
                    initial_state: Optional[Array] = None,
                    xamba: XambaConfig = XambaConfig(),
@@ -51,29 +61,14 @@ def selective_scan(u: Array, delta: Array, A: Array, B: Array, C: Array,
     b, l, d = u.shape
     n = A.shape[-1]
     uf = u.astype(jnp.float32)
-    df = delta.astype(jnp.float32)
     Af = A.astype(jnp.float32)
-    Bf = B.astype(jnp.float32)
-    Cf = C.astype(jnp.float32)
-
-    # Discretize (ZOH on A, Euler on B as in Mamba).
-    dA = df[..., None] * Af[None, None]                    # (b, l, d, n) log-decay
-    dBu = (df * uf)[..., None] * Bf[:, :, None, :]         # (b, l, d, n)
-
     h0 = (jnp.zeros((b, d, n), jnp.float32) if initial_state is None
           else initial_state.astype(jnp.float32))
 
     if mode == "sequential":
-        def step(h, t_in):
-            dA_t, dBu_t, C_t = t_in
-            h = jnp.exp(dA_t) * h + dBu_t
-            y = jnp.einsum("bdn,bn->bd", h, C_t)
-            return h, y
-        ins = (jnp.moveaxis(dA, 1, 0), jnp.moveaxis(dBu, 1, 0),
-               jnp.moveaxis(Cf, 1, 0))
-        hT, ys = jax.lax.scan(step, h0, ins)
-        y = jnp.moveaxis(ys, 0, 1)
+        y, hT = _scan_over_time(u, delta, Af, B, C, h0)
     elif mode == "associative":
+        dA, dBu = _discretize(uf, delta, Af, B)
         decay = jnp.exp(dA)                                # (b, l, d, n)
 
         def combine(c1, c2):
@@ -84,9 +79,11 @@ def selective_scan(u: Array, delta: Array, A: Array, B: Array, C: Array,
         a_sc, h_sc = jax.lax.associative_scan(
             combine, (decay, dBu), axis=1)
         h_all = h_sc + a_sc * h0[:, None]                  # fold initial state
-        y = jnp.einsum("bldn,bln->bld", h_all, Cf)
+        y = jnp.einsum("bldn,bln->bld", h_all, C.astype(jnp.float32))
         hT = h_all[:, -1]
     elif mode == "chunked":
+        dA, dBu = _discretize(uf, delta, Af, B)
+        Cf = C.astype(jnp.float32)
         # Pad to a chunk multiple with dt=0 steps (decay=1, input=0): exact
         # no-ops for outputs and final state, so any prefill-chunk length
         # works (mirrors core/ssd.py).
@@ -133,6 +130,39 @@ def selective_scan(u: Array, delta: Array, A: Array, B: Array, C: Array,
     if return_final_state:
         return y, hT
     return y
+
+
+def _discretize(uf: Array, delta: Array, Af: Array, B: Array
+                ) -> Tuple[Array, Array]:
+    """ZOH on A, Euler on B as in Mamba: the (b, l, d, n) log-decay
+    ``delta * A`` and input ``delta * u * B`` of every step at once."""
+    df = delta.astype(jnp.float32)
+    dA = df[..., None] * Af[None, None]
+    dBu = (df * uf)[..., None] * B.astype(jnp.float32)[:, :, None, :]
+    return dA, dBu
+
+
+def _scan_over_time(u: Array, delta: Array, Af: Array, B: Array, C: Array,
+                    h0: Array) -> Tuple[Array, Array]:
+    """One step per token with the state as the loop's carry.
+
+    Each step reads its ``(b, d)`` slices of ``u``/``delta`` and ``(b, n)``
+    slices of ``B``/``C``, and forms its decay, input, state update and
+    output over the state alone, so no ``(b, l, d, n)`` array exists.  The
+    carry is ``(b, n, d)``: ``d_inner`` on the lanes, ``d_state`` on the
+    sublanes.  Returns y (b, l, d) in f32 and the final state (b, d, n).
+    """
+    At = Af.T                                              # (n, d)
+
+    def step(h, t_in):
+        u_t, dt_t, B_t, C_t = (x.astype(jnp.float32) for x in t_in)
+        h = (jnp.exp(dt_t[:, None, :] * At[None]) * h
+             + (dt_t * u_t)[:, None, :] * B_t[:, :, None])
+        return h, jnp.sum(h * C_t[:, :, None], axis=1)
+
+    ins = tuple(jnp.moveaxis(x, 1, 0) for x in (u, delta, B, C))
+    hT, ys = jax.lax.scan(step, jnp.swapaxes(h0, 1, 2), ins)
+    return jnp.moveaxis(ys, 0, 1), jnp.swapaxes(hT, 1, 2)
 
 
 def selective_scan_decode_step(state: Array, u_t: Array, delta_t: Array,

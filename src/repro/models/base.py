@@ -306,7 +306,7 @@ class ModelConfig:
     ssm_ngroups: int = 1
     chunk_size: int = 256
     dt_rank: int = 0              # 0 -> ceil(d_model/16) (mamba1)
-    scan_mode: str = "associative"
+    scan_mode: str = "sequential"  # mamba1: sequential|associative|chunked
     ssd_dtype: str = "float32"    # SSD big-matmul dtype (bf16 = perf mode)
 
     # -- recurrentgemma ---------------------------------------------------------
