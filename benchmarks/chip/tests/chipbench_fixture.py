@@ -8,21 +8,43 @@ come from CPU readings at this size: the served bf16 path reads a
 ``gap`` of about 0.006, the float8 control about 0.05.
 
 These cells test the harness, not how closely the program follows the
-published models, so their configurations state the program's own
-RMSNorm epsilon (1e-6) and, for Mamba-2, its gated-norm order
-(``norm_before_gate``), where the published models differ (PERF.md,
-Open questions).
+published models, so their configurations state whatever norm settings
+the program states (``program_norm``): its RMSNorm epsilon and, for
+Mamba-2, its gated-norm order, where these may differ from the
+published models'.
 """
 import json
 import shutil
+import sys
 from pathlib import Path
 
 HERE = Path(__file__).resolve().parents[1]
 ROOT = HERE.parents[1]
+if str(HERE) not in sys.path:
+    sys.path.insert(0, str(HERE))
+
+import program  # noqa: E402
+
 LIMITS = {"gap": 0.03}
-# The program's own norm settings, by model family.
-PROGRAM_NORM = {"mamba2": {"norm_epsilon": 1e-6, "norm_before_gate": True},
-                "mamba": {"norm_epsilon": 1e-6}}
+# What the program computes where its ModelConfig states no norm settings:
+# RMSNorm at ``layers.norm``'s epsilon, and Mamba-2's gate after the norm.
+UNSTATED_NORM = {"norm_eps": 1e-6, "norm_before_gate": True}
+
+
+def program_norm(cfg: dict) -> dict:
+    """The reference's norm keys for what the program computes for ``cfg``.
+
+    Read from ``program.model_config(cfg)``: ``norm_eps`` as
+    ``norm_epsilon`` and, for Mamba-2, ``norm_before_gate`` (``mamba_ssm``'s
+    name and meaning); ``UNSTATED_NORM`` where the config has no such field.
+    """
+    mcfg = program.model_config(cfg)
+    out = {"norm_epsilon": getattr(mcfg, "norm_eps",
+                                   UNSTATED_NORM["norm_eps"])}
+    if mcfg.family == "mamba2":
+        out["norm_before_gate"] = getattr(mcfg, "norm_before_gate",
+                                          UNSTATED_NORM["norm_before_gate"])
+    return out
 
 
 def write(base: Path) -> dict:
@@ -32,11 +54,12 @@ def write(base: Path) -> dict:
     shutil.copytree(HERE / "metrics", base / "metrics", dirs_exist_ok=True)
     c2 = json.loads((HERE / "configs" / "mamba2-2.7b.json").read_text())
     c2.update(d_model=64, n_layer=2, vocab_size=128, d_state=16, headdim=16,
-              chunk_size=32, serve={"slots": 4, "chunk": 32},
-              **PROGRAM_NORM["mamba2"])
+              chunk_size=32, serve={"slots": 4, "chunk": 32})
+    c2.update(program_norm(c2))
     c1 = json.loads((HERE / "configs" / "mamba-130m.json").read_text())
     c1.update(d_model=64, n_layer=2, vocab_size=128, dt_rank=4,
-              serve={"slots": 4, "chunk": 16}, **PROGRAM_NORM["mamba"])
+              serve={"slots": 4, "chunk": 16})
+    c1.update(program_norm(c1))
     mixes = {
         "open": {"loop": "open", "rate_per_s": 4, "drain_cap_s": 30,
                  "prompt": {"dist": "lognormal", "median": 40, "sigma": 0.8,
