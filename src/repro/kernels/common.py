@@ -49,6 +49,24 @@ def head_expand(h: int, p: int):
     return ((cols >= rows * p) & (cols < rows * p + p)).astype(jnp.float32)
 
 
+def gate_then_rmsnorm(y, z, scale, *, eps: float, ngroups: int, silu):
+    """Mamba-2's published gated norm (``mamba_ssm``'s ``RMSNormGated``
+    with ``norm_before_gate=False``): ``rmsnorm(y * silu(z)) * scale`` in
+    float32, each group of ``d_inner / ngroups`` channels normalised on
+    its own.  ``y``, ``z``: ``(..., d_inner)`` of any dtype; ``scale``
+    broadcasts against them.  Returns float32.  Only static lane slices,
+    so kernel bodies call it as well as the XLA paths."""
+    x = y.astype(jnp.float32) * silu(z.astype(jnp.float32))
+    size = x.shape[-1] // ngroups
+    parts = []
+    for k in range(ngroups):
+        xk = x[..., k * size:(k + 1) * size]
+        ms = jnp.mean(xk * xk, axis=-1, keepdims=True)
+        parts.append(xk * jax.lax.rsqrt(ms + eps))
+    xn = parts[0] if ngroups == 1 else jnp.concatenate(parts, axis=-1)
+    return xn * scale.astype(jnp.float32)
+
+
 def round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 

@@ -196,9 +196,10 @@ def mamba2_step(z: Array, xbc: Array, dt: Array, conv_state: Array,
                 ngroups: int, head_dim: int,
                 silu: Callable = jax.nn.silu,
                 softplus: Callable = jax.nn.softplus,
-                eps: float = 1e-6,
+                eps: float,
                 interpret: bool = False) -> Tuple[Array, Array, Array]:
-    """Fused Mamba-2 decode step for one token.
+    """Fused Mamba-2 decode step for one token, its gated RMSNorm
+    (``common.gate_then_rmsnorm``) at ``eps``.
 
     z (b,di), xbc (b,dxbc), dt (b,h) — the ``in_proj`` splits;
     conv_state (b,w-1,dxbc), ssm_state (b,h,p,n); conv_w (w,dxbc);
@@ -234,9 +235,8 @@ def mamba2_step(z: Array, xbc: Array, dt: Array, conv_state: Array,
                              common.dot(jnp.exp(dt_f * _f32(a_ref)), E),
                              B, C, g)
         y = y + common.dot(_f32(d_ref), E) * xs              # D skip
-        ms = jnp.mean(y * y, axis=-1, keepdims=True)
-        yn = y * jax.lax.rsqrt(ms + eps) * _f32(nsc_ref)     # gated RMSNorm
-        out = yn * silu(_f32(z_ref)[0])
+        out = common.gate_then_rmsnorm(y, _f32(z_ref)[0], _f32(nsc_ref),
+                                       eps=eps, ngroups=g, silu=silu)
         y_ref[0] = out.astype(y_ref.dtype)
         nc_ref[0] = new_conv.astype(nc_ref.dtype)
         nst_ref[0] = new.astype(nst_ref.dtype)

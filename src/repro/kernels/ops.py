@@ -106,28 +106,31 @@ def sscan_step(state, u_t, delta_t, A, B_t, C_t, D=None, *,
                           interpret=interpret)
 
 
-@partial(jax.jit, static_argnames=("ngroups", "head_dim", "xamba",
+@partial(jax.jit, static_argnames=("ngroups", "head_dim", "eps", "xamba",
                                    "interpret"))
 def mamba2_decode_step(z, xbc, dt, conv_state, ssm_state, conv_w, conv_b,
                        dt_bias, A, D, norm_scale, *, ngroups: int,
-                       head_dim: int, xamba=None, interpret: bool = False):
+                       head_dim: int, eps: float, xamba=None,
+                       interpret: bool = False):
     """Fused Mamba-2 single-token step (conv + SiLU + softplus + SSD +
-    gated norm).  ``xamba`` (hashable config) bakes ActiBA tables in."""
+    gated norm at ``eps``).  ``xamba`` (hashable config) bakes ActiBA
+    tables in."""
     return _ds.mamba2_step(
         z, xbc, dt, conv_state, ssm_state, conv_w, conv_b, dt_bias, A, D,
-        norm_scale, ngroups=ngroups, head_dim=head_dim,
+        norm_scale, ngroups=ngroups, head_dim=head_dim, eps=eps,
         silu=pwl.activation("silu", xamba),
         softplus=pwl.activation("softplus", xamba), interpret=interpret)
 
 
-@partial(jax.jit, static_argnames=("ngroups", "head_dim", "chunk",
+@partial(jax.jit, static_argnames=("ngroups", "head_dim", "chunk", "eps",
                                    "xamba", "mode"))
 def mamba2_prefill(x, in_w, conv_state, ssm_state, conv_w, conv_b, dt_bias,
                    A, D, norm_scale, *, ngroups: int, head_dim: int,
-                   chunk: int, xamba=None, mode: str = "cumba"):
+                   chunk: int, eps: float, xamba=None, mode: str = "cumba"):
     """Fused Mamba-2 multi-token prefill (``XambaConfig.prefill``):
     in-projection (W8-fused when ``in_w`` is quantized) + conv + SiLU +
-    softplus(dt) + chunked SSD scan + gated norm in one pass.
+    softplus(dt) + chunked SSD scan + gated norm (at ``eps``) in one
+    pass.
 
     ``mode``: ``cumba`` = fused-structure XLA pipeline; ``pallas`` /
     ``pallas_interpret`` = the one-kernel Pallas pipeline.  Returns
@@ -139,7 +142,7 @@ def mamba2_prefill(x, in_w, conv_state, ssm_state, conv_w, conv_b, dt_bias,
     g, n = ngroups, ssm_state.shape[-1]
     zxbcdt = _pc.project_in(x, in_w)
     z, xbc, dt = jnp.split(zxbcdt, [di, 2 * di + 2 * g * n], axis=-1)
-    kwargs = dict(ngroups=g, head_dim=head_dim, chunk=chunk,
+    kwargs = dict(ngroups=g, head_dim=head_dim, chunk=chunk, eps=eps,
                   silu=pwl.activation("silu", xamba),
                   softplus=pwl.activation("softplus", xamba))
     if mode in ("pallas", "pallas_interpret"):

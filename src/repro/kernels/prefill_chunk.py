@@ -134,23 +134,6 @@ def _conv_window(conv_state: Array, xbc: Array, conv_w: Array,
     return conv, win[:, l:]
 
 
-def _gate_epilogue(y: Array, xs: Array, z: Array, D: Array,
-                   norm_scale: Array, silu: Callable, eps: float) -> Array:
-    """D skip + RMSNorm + SiLU(z) gate; y/xs (b, l, h, p), z (b, l, di).
-
-    Runs in the STREAM dtype with an fp32 norm interior — the exact
-    boundary-rounding discipline of the unfused chain (``layers.norm``),
-    so fused and unfused prefill agree even under bf16 params.
-    """
-    b, l, h, p = y.shape
-    sd = z.dtype
-    y = y.astype(sd) + xs.astype(sd) * D.astype(sd)[None, None, :, None]
-    yf = y.reshape(b, l, h * p).astype(jnp.float32)
-    ms = jnp.mean(yf * yf, axis=-1, keepdims=True)
-    yn = yf * jax.lax.rsqrt(ms + eps) * norm_scale.astype(jnp.float32)
-    return yn.astype(sd) * silu(z)
-
-
 # ----------------------------------------------------------------------------
 # Backend 1: fused-structure XLA pipeline (mode "cumba")
 # ----------------------------------------------------------------------------
@@ -160,7 +143,7 @@ def mamba2_prefill_xla(z: Array, xbc: Array, dt: Array, conv_state: Array,
                        dt_bias: Array, A: Array, D: Array,
                        norm_scale: Array, *, ngroups: int, head_dim: int,
                        chunk: int, silu: Callable, softplus: Callable,
-                       eps: float = 1e-6) -> Tuple[Array, Array, Array]:
+                       eps: float) -> Tuple[Array, Array, Array]:
     """Single-pass prefill: streams in, gated mixer output + states out.
 
     z: (b, l, di); xbc: (b, l, dxbc); dt: (b, l, h) RAW (pre-softplus);
@@ -206,7 +189,11 @@ def mamba2_prefill_xla(z: Array, xbc: Array, dt: Array, conv_state: Array,
             body, state0, (split(xdt), split(a), split(B), split(C)))
         y = jnp.moveaxis(ys, 0, 1).reshape(b, l, h, p)
 
-    out = _gate_epilogue(y, xs_r, z, D, norm_scale, silu, eps)
+    # D skip in the stream dtype, gated norm in fp32: the unfused chain's
+    # rounding boundaries, so fused and unfused prefill agree under bf16.
+    y = y.astype(sd) + xs_r.astype(sd) * D.astype(sd)[None, None, :, None]
+    out = common.gate_then_rmsnorm(y.reshape(b, l, di), z, norm_scale,
+                                   eps=eps, ngroups=g, silu=silu).astype(sd)
     return out, new_tail.astype(conv_state.dtype), new_ssm
 
 
@@ -293,11 +280,8 @@ def _prefill_kernel(width: int, di: int, g: int, p: int, n: int,
 
         d_ch = common.dot(d_ref[...].astype(jnp.float32), E)  # (1, di)
         y = y.astype(sd) + xs * d_ch.astype(sd)
-        yf = y.astype(jnp.float32)
-        ms = jnp.mean(yf * yf, axis=-1, keepdims=True)
-        yn = yf * jax.lax.rsqrt(ms + eps) * ns_ref[...].astype(jnp.float32)
-        out = yn.astype(sd) * \
-            silu(z_ref[0].astype(jnp.float32)).astype(sd)
+        out = common.gate_then_rmsnorm(y, z_ref[0], ns_ref[...], eps=eps,
+                                       ngroups=g, silu=silu)
 
         y_ref[0] = out.astype(y_ref.dtype)
         tail_scr[...] = win[L:]
@@ -313,7 +297,7 @@ def mamba2_prefill_pallas(z: Array, xbc: Array, dt: Array, conv_state: Array,
                           dt_bias: Array, A: Array, D: Array,
                           norm_scale: Array, *, ngroups: int, head_dim: int,
                           chunk: int, silu: Callable, softplus: Callable,
-                          eps: float = 1e-6, interpret: bool = False
+                          eps: float, interpret: bool = False
                           ) -> Tuple[Array, Array, Array]:
     """One-kernel prefill (shapes/contract as :func:`mamba2_prefill_xla`).
 

@@ -139,9 +139,20 @@ def sscan_step_ref(state, u_t, delta_t, A, B_t, C_t, D=None):
     return new, y.astype(u_t.dtype)
 
 
+def _gated_norm_ref(y, z, scale, *, eps, ngroups, silu):
+    """Mamba-2's gated RMSNorm on (..., di) in float32, as
+    ``mamba_ssm``'s ``RMSNormGated``: ``norm(y * silu(z))`` over groups
+    of di / ngroups channels."""
+    y = y.astype(jnp.float32) * silu(z.astype(jnp.float32))
+    yg = y.reshape(y.shape[:-1] + (ngroups, y.shape[-1] // ngroups))
+    ms = jnp.mean(yg * yg, axis=-1, keepdims=True)
+    return (yg * jax.lax.rsqrt(ms + eps)).reshape(y.shape) * \
+        scale.astype(jnp.float32)
+
+
 def mamba2_step_ref(z, xbc, dt, conv_state, ssm_state, conv_w, conv_b,
-                    dt_bias, A, D, norm_scale, *, ngroups, head_dim,
-                    silu=jax.nn.silu, softplus=jax.nn.softplus, eps=1e-6):
+                    dt_bias, A, D, norm_scale, *, ngroups, head_dim, eps,
+                    silu=jax.nn.silu, softplus=jax.nn.softplus):
     """Fused Mamba-2 decode-step oracle (shapes as kernels.decode_step)."""
     b, di = z.shape
     g, p = ngroups, head_dim
@@ -156,18 +167,15 @@ def mamba2_step_ref(z, xbc, dt, conv_state, ssm_state, conv_w, conv_b,
                     dt_bias.astype(jnp.float32)[None])
     new, y = ssd_step_ref(ssm_state, xs, dt_f, A, B, C)
     y = y + D.astype(jnp.float32)[None, :, None] * xs
-    yf = y.reshape(b, di)
-    ms = jnp.mean(yf * yf, axis=-1, keepdims=True)
-    yn = yf * jax.lax.rsqrt(ms + eps) * norm_scale.astype(jnp.float32)[None]
-    out = yn * silu(z.astype(jnp.float32))
+    out = _gated_norm_ref(y.reshape(b, di), z, norm_scale, eps=eps,
+                          ngroups=g, silu=silu)
     return (out.astype(z.dtype), new_conv.astype(conv_state.dtype),
             new.astype(jnp.float32))
 
 
 def mamba2_prefill_ref(z, xbc, dt, conv_state, ssm_state, conv_w, conv_b,
                        dt_bias, A, D, norm_scale, *, ngroups, head_dim,
-                       silu=jax.nn.silu, softplus=jax.nn.softplus,
-                       eps=1e-6):
+                       eps, silu=jax.nn.silu, softplus=jax.nn.softplus):
     """Fused prefill-pipeline oracle (shapes as kernels.prefill_chunk):
     plain-jnp conv + activations feeding the exact sequential SSD
     recurrence (``core.ssd.ssd_reference``), then the gated-norm
@@ -189,10 +197,8 @@ def mamba2_prefill_ref(z, xbc, dt, conv_state, ssm_state, conv_w, conv_b,
     dt_f = softplus(dt.astype(jnp.float32) + dt_bias.astype(jnp.float32))
     y, new_ssm = ssd_reference(xs, dt_f, A, B, C, initial_state=ssm_state)
     y = y + xs * D.astype(jnp.float32)[None, None, :, None]
-    yf = y.reshape(b, l, di)
-    ms = jnp.mean(yf * yf, axis=-1, keepdims=True)
-    yn = yf * jax.lax.rsqrt(ms + eps) * norm_scale.astype(jnp.float32)
-    out = yn * silu(z.astype(jnp.float32))
+    out = _gated_norm_ref(y.reshape(b, l, di), z, norm_scale, eps=eps,
+                          ngroups=g, silu=silu)
     return (out, win[:, l:].astype(conv_state.dtype),
             new_ssm.astype(jnp.float32))
 

@@ -3,7 +3,7 @@ from __future__ import annotations
 
 import dataclasses
 import warnings
-from typing import Optional, Tuple
+from typing import ClassVar, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -58,6 +58,12 @@ class DecodeAPI:
     def prefill_chunk(self, params, tokens, cache, index):
         raise NotImplementedError(
             f"{type(self).__name__} does not implement prefill_chunk")
+
+    def prefill_path(self, length: int) -> Tuple[str, Optional[str]]:
+        """``(path, reason)``: which multi-token mixer path a prefill
+        program of ``length`` tokens traces, for the engine's trace;
+        ``("n/a", None)`` for a model with one path only."""
+        return "n/a", None
 
     def verify_chunk(self, params, tokens, cache, index):
         """``prefill_chunk`` with per-position logits: ``(b, s, vocab)``
@@ -281,6 +287,12 @@ class ModelConfig:
 
     # -- norms / embeddings ---------------------------------------------------
     norm_type: str = "rmsnorm"    # rmsnorm | gemma_rmsnorm | layernorm
+    # Mamba-1 / Mamba-2 only (other families keep ``layers.norm``'s
+    # default), fixed at the published models' values, not options: the
+    # epsilon of every RMSNorm, and Mamba-2's gated-norm order in
+    # ``mamba_ssm``'s terms (False: ``norm(y * silu(z))``).
+    norm_eps: ClassVar[float] = 1e-5
+    norm_before_gate: ClassVar[bool] = False
     embed_scale: bool = False     # gemma: x *= sqrt(d_model)
     tie_embeddings: bool = True
 

@@ -53,6 +53,11 @@ class MambaLM(base.DecodeAPI):
                 cfg.d_model, cfg.vocab_size, axes=("embed", "vocab"))
         return specs
 
+    def prefill_path(self, length: int):
+        if self.v2:
+            return ssm.mamba2_prefill_path(self.cfg, length)
+        return super().prefill_path(length)
+
     # ---------------- trunk ----------------
     def _mixer_apply(self, p, x, state):
         if self.v2:
@@ -60,8 +65,8 @@ class MambaLM(base.DecodeAPI):
         return ssm.mamba1_apply(p, self.cfg, x, state)
 
     def _block(self, p, x, state):
-        h, new_state = self._mixer_apply(p["mixer"], layers.norm(p["ln"], x),
-                                         state)
+        h, new_state = self._mixer_apply(
+            p["mixer"], layers.norm(p["ln"], x, eps=self.cfg.norm_eps), state)
         return x + h, new_state
 
     def _trunk(self, params, x, states=None):
@@ -129,7 +134,7 @@ class MambaLM(base.DecodeAPI):
         return x
 
     def _logits(self, params, x) -> Array:
-        x = layers.norm(params["final_norm"], x)
+        x = layers.norm(params["final_norm"], x, eps=self.cfg.norm_eps)
         if self.cfg.tie_embeddings:
             return layers.unembed(params["embed"], x)
         return layers.linear(params["lm_head"], x).astype(jnp.float32)

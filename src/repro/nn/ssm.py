@@ -27,7 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import pwl, selective_scan as sscan, ssd as ssd_mod
-from repro.kernels.common import RG_LRU_C as _RG_C
+from repro.kernels.common import RG_LRU_C as _RG_C, gate_then_rmsnorm
 from repro.nn import layers, quant
 from repro.nn.params import ParamSpec
 
@@ -77,6 +77,28 @@ def mamba2_init_state(cfg, batch: int, dtype=jnp.float32) -> Mamba2State:
         ssm=jnp.zeros((batch, nheads, cfg.ssm_head_dim, n), jnp.float32))
 
 
+def mamba2_prefill_path(cfg, length: int) -> Tuple[str, Optional[str]]:
+    """Which multi-token path ``mamba2_apply`` takes for ``length`` tokens:
+    ``("fused-cumba" | "fused-pallas", None)`` for the fused pipeline
+    (``kernels/prefill_chunk.py``), else ``("unfused", reason)``.  Decided
+    at trace time from the config and the shape alone.  The fused pipeline
+    takes RAW dt and the live conv tail, so it cannot hide ineligible
+    shapes behind dt=0 padding the way ``core/ssd.py`` does: it requires
+    exact chunking."""
+    mode = cfg.xamba.prefill
+    if mode == "naive":
+        return "unfused", "prefill mode naive"
+    chunk = min(cfg.chunk_size, length)
+    if cfg.ssd_dtype != "float32":
+        return "unfused", (f"ssd_dtype={cfg.ssd_dtype} (fused prefill is "
+                           "fp32-only)")
+    if length % chunk:
+        return "unfused", f"seqlen {length} not a multiple of chunk {chunk}"
+    if mode == "pallas" and chunk % 64:
+        return "unfused", f"chunk {chunk} not a multiple of 64 (MXU tiling)"
+    return ("fused-cumba" if mode == "cumba" else "fused-pallas"), None
+
+
 def _mamba2_decode_naive(params: dict, cfg, x: Array, state: Mamba2State
                          ) -> Tuple[Array, Mamba2State]:
     """The unfused dense step (the pre-refactor / NPU-baseline op chain):
@@ -104,8 +126,8 @@ def _mamba2_decode_naive(params: dict, cfg, x: Array, state: Mamba2State
         state.ssm, xs[:, 0], dt[:, 0], A, B.reshape(b, g, n),
         C.reshape(b, g, n), mode="naive")
     y = y[:, None] + xs * params["D"].astype(x.dtype)[None, None, :, None]
-    y = y.reshape(b, l, d_inner)
-    y = layers.norm(params["norm"], y) * silu(z)
+    y = gate_then_rmsnorm(y.reshape(b, l, d_inner), z, params["norm"]["scale"],
+                          eps=cfg.norm_eps, ngroups=g, silu=silu)
     out = layers.linear(params["out_proj"], y.astype(x.dtype))
     return out, Mamba2State(new_conv, new_ssm)
 
@@ -134,7 +156,7 @@ def _mamba2_decode(params: dict, cfg, x: Array, state: Mamba2State
             z, xbc, dt, state.conv, state.ssm,
             params["conv"]["w"], params["conv"]["b"], params["dt_bias"],
             A, params["D"], params["norm"]["scale"],
-            ngroups=g, head_dim=p_hd, xamba=xamba,
+            ngroups=g, head_dim=p_hd, eps=cfg.norm_eps, xamba=xamba,
             interpret=(mode == "pallas_interpret"))
     else:
         silu = pwl.activation("silu", xamba)
@@ -150,7 +172,9 @@ def _mamba2_decode(params: dict, cfg, x: Array, state: Mamba2State
             state.ssm, xs, dt_f, A, B.reshape(b, g, n), C.reshape(b, g, n),
             mode=mode)
         y = y + xs * params["D"].astype(x.dtype)[None, :, None]
-        y = layers.norm(params["norm"], y.reshape(b, d_inner)) * silu(z)
+        y = gate_then_rmsnorm(y.reshape(b, d_inner), z,
+                              params["norm"]["scale"], eps=cfg.norm_eps,
+                              ngroups=g, silu=silu)
     out = layers.linear(params["out_proj"], y.astype(x.dtype))[:, None]
     return out, Mamba2State(new_conv, new_ssm)
 
@@ -168,38 +192,27 @@ def mamba2_apply(params: dict, cfg, x: Array,
         return _mamba2_decode(params, cfg, x, state)
 
     pf_mode = xamba.prefill
+    path, reason = mamba2_prefill_path(cfg, l)
+    if path != "unfused":
+        from repro.kernels import ops as kops
+        if state is not None:
+            conv_state, init = state.conv, state.ssm
+        else:
+            d_xbc = d_inner + 2 * g * n
+            conv_state = jnp.zeros((b, cfg.d_conv - 1, d_xbc), x.dtype)
+            init = jnp.zeros((b, nheads, p_hd, n), jnp.float32)
+        A = -jnp.exp(params["A_log"].astype(jnp.float32))
+        y, new_conv, new_ssm = kops.mamba2_prefill(
+            x, params["in_proj"]["w"], conv_state, init,
+            params["conv"]["w"], params["conv"]["b"],
+            params["dt_bias"], A, params["D"], params["norm"]["scale"],
+            ngroups=g, head_dim=p_hd, chunk=min(cfg.chunk_size, l),
+            eps=cfg.norm_eps, xamba=xamba, mode=pf_mode)
+        out = layers.linear(params["out_proj"], y.astype(x.dtype))
+        new_state = (Mamba2State(new_conv, new_ssm)
+                     if state is not None else None)
+        return out, new_state
     if pf_mode != "naive":
-        # Trace-time eligibility gate: the fused pipeline takes RAW dt and
-        # the live conv tail, so it cannot hide ineligible shapes behind
-        # dt=0 padding the way ``core/ssd.py`` does — it requires exact
-        # chunking and falls back to the unfused chain otherwise.
-        chunk = min(cfg.chunk_size, l)
-        reason = None
-        if cfg.ssd_dtype != "float32":
-            reason = f"ssd_dtype={cfg.ssd_dtype} (fused prefill is fp32-only)"
-        elif l % chunk:
-            reason = f"seqlen {l} not a multiple of chunk {chunk}"
-        elif pf_mode == "pallas" and chunk % 64:
-            reason = f"chunk {chunk} not a multiple of 64 (MXU tiling)"
-        if reason is None:
-            from repro.kernels import ops as kops
-            if state is not None:
-                conv_state, init = state.conv, state.ssm
-            else:
-                d_xbc = d_inner + 2 * g * n
-                conv_state = jnp.zeros((b, cfg.d_conv - 1, d_xbc), x.dtype)
-                init = jnp.zeros((b, nheads, p_hd, n), jnp.float32)
-            A = -jnp.exp(params["A_log"].astype(jnp.float32))
-            y, new_conv, new_ssm = kops.mamba2_prefill(
-                x, params["in_proj"]["w"], conv_state, init,
-                params["conv"]["w"], params["conv"]["b"],
-                params["dt_bias"], A, params["D"], params["norm"]["scale"],
-                ngroups=g, head_dim=p_hd, chunk=chunk, xamba=xamba,
-                mode=pf_mode)
-            out = layers.linear(params["out_proj"], y.astype(x.dtype))
-            new_state = (Mamba2State(new_conv, new_ssm)
-                         if state is not None else None)
-            return out, new_state
         # Fires once per compiled shape (trace-time), not per call.
         log.warning("fused prefill (%s) skipped: %s — running the unfused "
                     "chain", pf_mode, reason)
@@ -230,8 +243,8 @@ def mamba2_apply(params: dict, cfg, x: Array,
         matmul_dtype=mm_dtype)
 
     y = y + xs * params["D"].astype(x.dtype)[None, None, :, None]
-    y = y.reshape(b, l, d_inner)
-    y = layers.norm(params["norm"], y) * silu(z)
+    y = gate_then_rmsnorm(y.reshape(b, l, d_inner), z, params["norm"]["scale"],
+                          eps=cfg.norm_eps, ngroups=g, silu=silu)
     out = layers.linear(params["out_proj"], y.astype(x.dtype))
     new_state = Mamba2State(new_conv, new_ssm) if state is not None else None
     return out, new_state

@@ -438,6 +438,19 @@ class ContinuousEngine(EngineBase):
                             if self.spec_k else None)
         self._pid_draft = (reg.program_id("draft")
                            if self.spec_k else None)
+        # Which multi-token mixer path each prefill program traced
+        # (``model.prefill_path``), decided by the config and its length
+        # alone: one instant per compiled program, and on every chunk span.
+        lengths = ([(self._pid_chunk, self.chunk)] if self.chunk else
+                   [(self._pid_prefill, b) for b in self.buckets])
+        if self.spec_k:
+            lengths.append((self._pid_verify, self.spec_k))
+        for pid, length in lengths:
+            path, reason = self.model.prefill_path(length)
+            self.tracer.instant("mixer_path", program=pid, tokens=length,
+                                path=path, reason=reason)
+        self._chunk_path = (self.model.prefill_path(self.chunk)[0]
+                            if self.chunk else None)
 
     def _observe_step(self, monitor: StepMonitor, kind: str,
                       dt_s: float) -> None:
@@ -1096,7 +1109,8 @@ class ContinuousEngine(EngineBase):
             off = self._pref_off[i]
             tokens[i] = self._pref_toks[i][off:off + C]
         with self.tracer.span("prefill_chunk", rows=len(rows),
-                              tokens=C * len(rows), program=self._pid_chunk):
+                              tokens=C * len(rows), program=self._pid_chunk,
+                              path=self._chunk_path):
             t0 = time.perf_counter()
             toks_dev = jnp.asarray(tokens)
             off_dev = jnp.asarray(self._pref_off)

@@ -36,6 +36,7 @@ DX = DI + 2 * G * N
 DI1, N1, R1 = 1536, 16, 48              # mamba-130m
 WD = 2560                               # recurrentgemma-2b lru_width
 L = 256                                 # mamba2-130m chunk_size
+EPS = 1e-5                              # Mamba-2's published norm epsilon
 
 
 @pytest.fixture(scope="module")
@@ -60,7 +61,7 @@ def one_chip():
 
 
 def _mamba2_step(*a):
-    return decode_step.mamba2_step(*a, ngroups=G, head_dim=P)
+    return decode_step.mamba2_step(*a, ngroups=G, head_dim=P, eps=EPS)
 
 
 def _mamba1_step(*a):
@@ -70,7 +71,7 @@ def _mamba1_step(*a):
 def _prefill(*a):
     return prefill_chunk.mamba2_prefill_pallas(
         *a, ngroups=G, head_dim=P, chunk=L, silu=jax.nn.silu,
-        softplus=jax.nn.softplus)
+        softplus=jax.nn.softplus, eps=EPS)
 
 
 def _ssd_chunk(x, a_cum, b, c):
