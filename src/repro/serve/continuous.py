@@ -957,14 +957,13 @@ class ContinuousEngine(EngineBase):
             self.metrics.record_prefill(bucket * len(group), t1 - t0)
             lg = self._logits_to_host(logits, "prefill")
             # First tokens sample at position = bucket (tokens consumed so
-            # far), keyed per owning request — see _sample_rows.
-            uids = np.zeros(self.slots, np.int64)
-            for row, (_, req) in enumerate(group):
-                uids[row] = req.uid
-            with self.tracer.span("sample", phase="prefill", rows=self.slots,
-                                  used=len(group)):
-                first = self._sample_rows(
-                    lg, uids, np.full(self.slots, bucket, np.int64))
+            # far), keyed per owning request — see _sample_rows.  Only the
+            # group's rows (the first len(group)) are drawn.
+            n = len(group)
+            uids = np.array([req.uid for _, req in group], np.int64)
+            with self.tracer.span("sample", phase="prefill", rows=n, used=n):
+                first = self._sample_rows(lg[:n], uids,
+                                          np.full(n, bucket, np.int64))
             self.pool.insert_rows(cache,
                                   [row for row in range(len(group))],
                                   [slot for slot, _ in group])
@@ -1172,24 +1171,25 @@ class ContinuousEngine(EngineBase):
                 self._finished.append(req)
             done_rows = kept
         if done_rows:
-            uids = np.zeros(self.slots, np.int64)
-            poss = np.zeros(self.slots, np.int64)
-            for i in done_rows:
-                uids[i] = self._pref_req[i].uid
-                poss[i] = len(self._pref_toks[i])
-            with self.tracer.span("sample", phase="prefill", rows=self.slots,
-                                  used=len(done_rows)):
-                first = self._sample_rows(lg, uids, poss)
+            # Only the finished prompts' rows are drawn; first[j] is the
+            # first token of slot done_rows[j].
+            uids = np.array([self._pref_req[i].uid for i in done_rows],
+                            np.int64)
+            poss = np.array([len(self._pref_toks[i]) for i in done_rows],
+                            np.int64)
+            with self.tracer.span("sample", phase="prefill",
+                                  rows=len(done_rows), used=len(done_rows)):
+                first = self._sample_rows(lg[done_rows], uids, poss)
             # Row i prefilled in the second pool becomes slot i's decode
             # state (same index — the slot was reserved at admission).
             self.pool.insert_rows(self._ppool.cache, done_rows, done_rows)
             t_first = time.time()
-            for i in done_rows:
+            for i, tok in zip(done_rows, first):
                 req = self._pref_req[i]
                 span = len(self._pref_toks[i])
                 self._pref_req[i] = None
                 self._pref_toks[i] = None
-                self._start_tenant(i, req, span, int(first[i]), t_first)
+                self._start_tenant(i, req, span, int(tok), t_first)
         return C * len(rows)
 
     # ------------------------------------------------------------------
@@ -1395,9 +1395,15 @@ class ContinuousEngine(EngineBase):
             self._observe_step(self.monitor_decode, "decode", t1 - t0)
             self.metrics.record_step(len(live), t1 - t0)
             lg = self._logits_to_host(logits, "decode")
-            with self.tracer.span("sample", phase="decode", rows=self.slots,
+            # Only live rows are drawn (all of lg, uncopied, when every
+            # slot is live); dead rows get no token.
+            with self.tracer.span("sample", phase="decode", rows=len(live),
                                   used=len(live)):
-                nxt = self._sample_rows(lg, self._row_uids(), self._pos + 1)
+                drawn = self._sample_rows(
+                    lg if len(live) == self.slots else lg[live],
+                    [self._slot_req[i].uid for i in live], self._pos[live] + 1)
+            nxt = np.zeros(self.slots, np.int32)
+            nxt[live] = drawn
             # Dead slots decode into a sink: their position pins to the last
             # cache column until a refill overwrites the whole row.
             self._pos = np.minimum(self._pos + 1, self.max_seq - 1)

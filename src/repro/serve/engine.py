@@ -267,7 +267,13 @@ class EngineBase:
         ``(seed, uid, position)``, so a token's draw doesn't depend on
         slot assignment, batch composition, or whether it came from a
         decode step or a speculative verify chunk — spec-on/off streams
-        match even under temperature (``serve/sampling.py``)."""
+        match even under temperature (``serve/sampling.py``).
+
+        Row ``i`` of ``logits`` belongs to ``uids[i]`` at ``positions[i]``.
+        Callers pass only the rows whose token they keep (a turnover's
+        finished prompts, the live decode slots): the draw costs per row,
+        and a subset draws the same noise its rows get in a full batch.
+        The ``sample`` span's ``rows`` counts the rows drawn."""
         return sampling.sample_keyed(np.asarray(logits, np.float32),
                                      self.cfg.temperature, self.cfg.seed,
                                      uids, positions)

@@ -518,8 +518,8 @@ def _assert_host_spans_outside_programs(events):
 def test_turnover_poll_emits_host_round_trip_siblings():
     """One traced chunked poll in which a prompt finishes next to a
     decoding request: the program spans, ``logits_to_host`` and
-    ``sample`` are siblings under ``poll``, with the rows each draws and
-    the rows whose token is kept."""
+    ``sample`` are siblings under ``poll``, with the rows each copies or
+    draws and the rows whose token is kept."""
     model, params = _model_params()
     slots = 4
     eng = ContinuousEngine(model, params, ServeConfig(
@@ -550,10 +550,11 @@ def test_turnover_poll_emits_host_round_trip_siblings():
 
     by_phase = {(e["name"], e["args"]["phase"]): e["args"] for e in kids
                 if e["name"] in HOST_SPANS}
+    # the draw takes only the rows whose token is kept; the copy is whole
     assert by_phase[("sample", "prefill")] == {"phase": "prefill",
-                                               "rows": slots, "used": 1}
+                                               "rows": 1, "used": 1}
     assert by_phase[("sample", "decode")] == {"phase": "decode",
-                                              "rows": slots, "used": 2}
+                                              "rows": 2, "used": 2}
     assert by_phase[("logits_to_host", "prefill")]["rows"] == slots
     assert by_phase[("logits_to_host", "decode")]["rows"] == slots
     (dec,) = [e for e in kids if e["name"] == "decode_step"]

@@ -383,6 +383,60 @@ def test_engine_temperature_sampling_deterministic():
     assert run_once() == run_once()
 
 
+@pytest.mark.parametrize("chunk", [None, 8], ids=["bucket", "chunked"])
+@pytest.mark.parametrize("family", ["mamba1", "mamba2"])
+def test_keyed_draw_takes_only_kept_rows(family, chunk):
+    """Every ``_sample_rows`` call carries only rows whose token the
+    engine keeps, and each served token is the keyed draw of its own
+    logits row: a turnover draws its finished prompts, decode its live
+    slots, and the tokens land back in the right slots."""
+    model, params = _model_params(family)
+    rng = np.random.default_rng(43)
+    seed = 11
+    eng = ContinuousEngine(model, params, ServeConfig(
+        max_batch=4, prefill_buckets=(16,), max_new_tokens=8,
+        prefill_chunk=chunk, temperature=1.0, seed=seed))
+    # Staggered prompt lengths and budgets: turnovers land next to running
+    # decode, and the tail leaves slots dead.
+    lengths = [5, 12, 9, 3, 14, 7, 11]
+    budgets = [3, 7, 2, 5, 4, 8, 1]
+    for n, m in zip(lengths, budgets):
+        eng.submit(rng.integers(1, V, n).tolist(), m)
+
+    inner = eng._sample_rows
+    drawn = {}              # uid -> {position: logits row}
+    calls = []
+
+    def sample_rows(logits, uids, positions):
+        # Same shape as the benchmark's capture: row i belongs to uids[i].
+        lg = np.asarray(logits, np.float32)
+        assert len(lg) == len(uids) == len(positions)
+        finished = {r.uid for r in eng._finished}
+        calls.append(len(uids))
+        for i, u in enumerate(uids):
+            assert u != 0 and u not in finished, (u, finished)
+            assert int(positions[i]) not in drawn.get(int(u), {})
+            drawn.setdefault(int(u), {})[int(positions[i])] = lg[i].copy()
+        assert len(set(int(u) for u in uids)) == len(uids)
+        return inner(logits, uids, positions)
+
+    eng._sample_rows = sample_rows
+    done = eng.run()
+    assert len(done) == len(lengths)
+    assert all(r.status == "ok" for r in done)
+    # no row is drawn only to be thrown away
+    assert sum(calls) == sum(len(r.out_tokens) for r in done)
+    assert min(calls) < 4       # some draws took fewer rows than slots
+    for r in done:
+        rows = drawn[r.uid]
+        p0 = min(rows)
+        assert sorted(rows) == list(range(p0, p0 + len(r.out_tokens)))
+        for k, tok in enumerate(r.out_tokens):
+            want = sampling.sample_keyed(rows[p0 + k][None], 1.0, seed,
+                                         [r.uid], [p0 + k])
+            assert tok == int(want[0]), (r.uid, k)
+
+
 # ---------------------------------------------------------------------------
 # metrics / accounting / streaming
 # ---------------------------------------------------------------------------
